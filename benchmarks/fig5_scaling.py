@@ -224,6 +224,7 @@ def run_combo(name: str, steps: int = 2) -> dict:
     return {
         "combo": name, **spec,
         "n_processes": jax.process_count(),
+        "backend": jax.default_backend(),
         "final_loss": step_rows[-1]["loss_new"],
         "steps": step_rows,
         "executed": counts.per_device(len(jax.local_devices())),
@@ -270,16 +271,20 @@ def run_bench(tiny: bool = False, out_path: str = JSON_OUT, log=print) -> dict:
     steps = 2
     log(f"fig5 executed series: mlp{EXEC_DIMS} batch={EXEC_BATCH} "
         f"K={EXEC_K} steps={steps} combos={list(EXEC_COMBOS)}")
+    # The parent never touches a device: the workers ran the series, so the
+    # backend is theirs (multiproc pins them to the CPU).
+    executed = run_executed(steps, log)
+    (backend,) = {r["backend"] for r in executed}
     result = {
         "schema": 1,
         "meta": {
             "timit_dims": list(TIMIT_FIG5),
             "exec_dims": list(EXEC_DIMS), "exec_batch": EXEC_BATCH,
             "exec_K": EXEC_K, "exec_steps": steps, "tiny": tiny,
-            "backend": jax.default_backend(),
+            "backend": backend,
         },
         "projection": [r for B in BATCHES for r in projection_records(B)],
-        "executed": run_executed(steps, log),
+        "executed": executed,
     }
     with open(out_path, "w") as f:
         json.dump(result, f, indent=1)

@@ -43,6 +43,7 @@ from typing import Any, Callable, Iterator, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 
 from ..obs import telemetry as _telemetry
 
@@ -52,9 +53,11 @@ from ..obs import telemetry as _telemetry
 # can tell "watchdog fired" apart from an ordinary crash in its logs.
 EXIT_WATCHDOG = 87
 
-# Primitive names that move data across mesh axes (psum covers pmean).
+# Primitive names that move data across mesh axes. A pmean binds one
+# ``psum`` per reduced leaf (``psum_invariant`` inside a shard_map with
+# ``check_vma=True``).
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmin", "pmax", "ppermute", "all_gather",
+    "psum", "psum_invariant", "pmin", "pmax", "ppermute", "all_gather",
     "all_to_all", "reduce_scatter",
 })
 
@@ -209,8 +212,8 @@ def collective_watchdog(timeout_s: float,
 def preduce(tree: Any, axes: Sequence[str] | str, tag: str = "reduce"):
     """``lax.pmean`` over a pytree, tagged for executed-count auditing.
 
-    One ``preduce`` call = one logical collective (jax binds a single
-    multi-operand psum for the whole pytree). When tracing happens inside
+    One ``preduce`` call = one logical collective (jax binds one psum per
+    leaf, which XLA's all-reduce combiner may merge). When tracing happens inside
     :func:`count_executed`, a debug callback rides along and fires once
     per execution per local device — inside ``while_loop`` bodies too,
     which is the whole point: loop-borne collectives are counted at their
@@ -267,9 +270,9 @@ def _sub_jaxprs(eqn) -> Iterator:
     for val in eqn.params.values():
         vals = val if isinstance(val, (list, tuple)) else (val,)
         for v in vals:
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, jex_core.ClosedJaxpr):
                 yield v.jaxpr
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, jex_core.Jaxpr):
                 yield v
 
 
@@ -283,7 +286,7 @@ def jaxpr_collective_counts(jaxpr) -> dict:
     cond jaxpr, which executes once per trip — multiply by the trip count
     (= the solver's reported syncs) to predict executed collectives.
     """
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     out = {"top": collections.Counter(), "while_body": collections.Counter()}
 

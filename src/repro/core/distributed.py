@@ -100,9 +100,7 @@ from typing import Any, Callable, Sequence
 
 import jax
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-from . import _shard_map_compat  # noqa: F401  (while/cond replication rules)
 from .collectives import preduce
 from .hf import HFConfig, hf_step
 
@@ -143,17 +141,23 @@ def data_parallel_hf_step(
     # its full *local* gradient contribution (no cross-worker reduction
     # appears in the transpose); pmean-ing the AD outputs — (1/N)Σ_w g_w,
     # matching the pmean'd loss — is Alg. 2's "reduce to root", one reduce
-    # for g and one per Krylov iteration. Replication checking stays ON so
-    # out_specs=P() is verified end-to-end (the while_loop replication rules
-    # come from _shard_map_compat).
+    # for g and one per Krylov iteration. The varying-manual-axes check is
+    # OFF on purpose: under it, reverse mode through the replicated params
+    # inserts its own per-leaf psum at every implicit pvary, on top of the
+    # explicit, audited grad_reduce — 21 all-reduces instead of 6 in the
+    # compiled bicgstab step of a 4-device mesh — and every pallas_call (flat
+    # backend, flash attention) would have to declare the vma of its
+    # outputs. That the outputs really are replicated is checked by
+    # tests/test_distributed.py (N-device step == 1-device step) instead.
     def grad_reduce(t):
         return preduce(t, axes, tag="grad_hvp")
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(), P(axes)),
         out_specs=(P(), P(), P()),
+        check_vma=False,
     )
     def step(params, state, batch):
         return hf_step(

@@ -14,9 +14,10 @@ dot products they feed removes whole HBM passes:
                         the s-step solvers' all-dots-for-s-iterations reduce
                         (core/sstep.py).
 
-1-D grid over VMEM-sized chunks; per-block partial sums land in a
-(n_blocks,)-shaped output reduced by the (tiny) jnp.sum in ops.py. All
-accumulation in f32.
+1-D grid over VMEM-sized chunks; each grid step writes its per-block
+partial sums as scalars into a (n_blocks,) SMEM output (a rank-1 VMEM block
+of one element is not a legal Mosaic tile), reduced by the (tiny) jnp.sum in
+ops.py. All accumulation in f32.
 """
 from __future__ import annotations
 
@@ -25,8 +26,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 64 * 1024  # 64k f32 elements = 256 KiB per operand tile in VMEM
+# whole-array scalar output resident in SMEM across the (sequential) grid
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _x_update_kernel(alpha_ref, gamma_ref, x_ref, p_ref, s_ref, o_ref):
@@ -64,8 +68,9 @@ def _residual_dots_kernel(gamma_ref, s_ref, As_ref, r0s_ref, r_ref, d1_ref, d2_r
     g = gamma_ref[0]
     r = s_ref[...].astype(jnp.float32) - g * As_ref[...].astype(jnp.float32)
     r_ref[...] = r
-    d1_ref[0] = jnp.sum(r * r0s_ref[...].astype(jnp.float32))
-    d2_ref[0] = jnp.sum(r * r)
+    i = pl.program_id(0)
+    d1_ref[i] = jnp.sum(r * r0s_ref[...].astype(jnp.float32))
+    d2_ref[i] = jnp.sum(r * r)
 
 
 def residual_dots(s, As, r0s, gamma, *, block=BLOCK, interpret=False):
@@ -82,11 +87,7 @@ def residual_dots(s, As, r0s, gamma, *, block=BLOCK, interpret=False):
             pl.BlockSpec((block,), lambda i: (i,)),
             pl.BlockSpec((block,), lambda i: (i,)),
         ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
+        out_specs=[pl.BlockSpec((block,), lambda i: (i,)), _SMEM, _SMEM],
         out_shape=[
             jax.ShapeDtypeStruct((n,), jnp.float32),
             jax.ShapeDtypeStruct((nb,), jnp.float32),
@@ -139,8 +140,9 @@ def dots_block(U, V, *, block=BLOCK_GRAM, interpret=False):
 def _dot2_kernel(u_ref, v_ref, d1_ref, d2_ref):
     u = u_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
-    d1_ref[0] = jnp.sum(u * v)
-    d2_ref[0] = jnp.sum(v * v)
+    i = pl.program_id(0)
+    d1_ref[i] = jnp.sum(u * v)
+    d2_ref[i] = jnp.sum(v * v)
 
 
 def dot2(u, v, *, block=BLOCK, interpret=False):
@@ -154,10 +156,7 @@ def dot2(u, v, *, block=BLOCK, interpret=False):
             pl.BlockSpec((block,), lambda i: (i,)),
             pl.BlockSpec((block,), lambda i: (i,)),
         ],
-        out_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
+        out_specs=[_SMEM, _SMEM],
         out_shape=[
             jax.ShapeDtypeStruct((nb,), jnp.float32),
             jax.ShapeDtypeStruct((nb,), jnp.float32),

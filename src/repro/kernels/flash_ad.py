@@ -97,25 +97,27 @@ def flash_bwd_passes(q, k, v, o, lse, do, **kkw):
     pass, the Pallas dK/dV pass, and the GQA group-sum (f32 partials).
     The single implementation behind both the linear_call transpose (what
     jax.grad executes) and the public ops.flash_attention_bwd wrapper the
-    kernel tests pin — one copy, no drift."""
-    delta = jnp.einsum("bshd,bshd->bsh", o.astype(jnp.float32),
-                       do.astype(jnp.float32)).transpose(0, 2, 1)
+    kernel tests pin — one copy, no drift. Head-major operands:
+    q/o/do (B,H,Sq,hd), k/v (B,KV,Sk,hd), lse (B,H,Sq)."""
+    delta = jnp.einsum("bhsd,bhsd->bhs", o.astype(jnp.float32),
+                       do.astype(jnp.float32))
     dq = fa.flash_attention_dq(q, k, v, do, lse, delta, **kkw)
     dkh, dvh = fa.flash_attention_dkv(q, k, v, do, lse, delta, **kkw)
-    B, _, H, hd = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    B, H, _, hd = q.shape
+    KV, Sk = k.shape[1], k.shape[2]
     G = H // KV
-    dk = dkh.reshape(B, Sk, KV, G, hd).sum(3)
-    dv = dvh.reshape(B, Sk, KV, G, hd).sum(3)
+    dk = dkh.reshape(B, KV, G, Sk, hd).sum(2)
+    dv = dvh.reshape(B, KV, G, Sk, hd).sum(2)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 def flash_jvp_pass(q, k, v, o, lse, qt, kt, vt, **kkw):
     """The attention JVP from the stored lse: the Pallas tangent pass plus
     the ȯ = g − t ∘ o finish (and l̇se = t). Single implementation behind
-    the linear_call tangent and ops.flash_attention_jvp."""
+    the linear_call tangent and ops.flash_attention_jvp (head-major
+    operands, as flash_bwd_passes)."""
     g, t = fa.flash_attention_jvp(q, k, v, qt, kt, vt, lse, **kkw)
-    ot = g - t.transpose(0, 2, 1)[..., None] * o.astype(jnp.float32)
+    ot = g - t[..., None] * o.astype(jnp.float32)
     return ot.astype(o.dtype), t
 
 
@@ -134,15 +136,15 @@ def _chunked_attention(q, k, v, bias=None, *, causal, window, scale,
     same O(Sk·blk) bound for all of them (P tiles are recomputed, not
     stored). ``bias``: optional (B|1, Sq, Sk) additive logit bias, sliced
     per query block (constant — differentiation passes it through as a
-    zero-tangent const).
+    zero-tangent const). Head-major operands, as the kernels take them.
     """
-    B, S, H, hd = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    B, H, S, hd = q.shape
+    KV, T = k.shape[1], k.shape[2]
     G = H // KV
     blk = min(blk, S)
     nb = S // blk
     f32 = jnp.float32
-    qs = q.reshape(B, nb, blk, KV, G, hd).transpose(1, 0, 2, 3, 4, 5)
+    qs = q.reshape(B, KV, G, nb, blk, hd).transpose(3, 0, 1, 2, 4, 5)
     if bias is not None:
         bias = jnp.broadcast_to(bias, (B, S, T))
         bias = bias.reshape(B, nb, blk, T).transpose(1, 0, 2, 3)
@@ -150,8 +152,8 @@ def _chunked_attention(q, k, v, bias=None, *, causal, window, scale,
         bias = jnp.zeros((nb, 1, 1, 1), f32)
 
     def body(_, x):
-        qb, bb, i0 = x                              # qb: (B, blk, KV, G, hd)
-        s = jnp.einsum("bskgh,btkh->bkgst", qb, k,
+        qb, bb, i0 = x                              # qb: (B, KV, G, blk, hd)
+        s = jnp.einsum("bkgsh,bkth->bkgst", qb, k,
                        preferred_element_type=f32) * scale
         s = s + bb[:, None, None]
         mask = fa.position_mask(i0 + jnp.arange(blk)[:, None],
@@ -162,13 +164,13 @@ def _chunked_attention(q, k, v, bias=None, *, causal, window, scale,
         m_safe = jnp.where(m <= NEG_INF / 2, 0.0, m)
         p = jnp.where(mask[None, None, None], jnp.exp(s - m_safe), 0.0)
         l = jnp.sum(p, axis=-1, keepdims=True)
-        ob = jnp.einsum("bkgst,btkh->bskgh", p / jnp.where(l <= 0.0, 1.0, l),
+        ob = jnp.einsum("bkgst,bkth->bkgsh", p / jnp.where(l <= 0.0, 1.0, l),
                         v, preferred_element_type=f32)
-        return None, ob.reshape(B, blk, H, hd).astype(q.dtype)
+        return None, ob.reshape(B, H, blk, hd).astype(q.dtype)
 
     _, ys = jax.lax.scan(jax.checkpoint(body), None,
                          (qs, bias, jnp.arange(nb) * blk))
-    return ys.transpose(1, 0, 2, 3, 4).reshape(B, S, H, hd)
+    return ys.transpose(1, 2, 0, 3, 4).reshape(B, H, S, hd)
 
 
 # -------------------------------------------------------- per-config entry --
@@ -278,12 +280,11 @@ def flash_mha(q, k, v, *, causal=True, window=None, scale=None,
     valid_len = Sk if Skp != Sk else None
     entry = _fa_entry(causal, window, scale, blk_q, blk_k, bool(interpret),
                       valid_len, second_order_active(), bias is not None)
-    if Sqp != Sq:
-        qpad = ((0, 0), (0, Sqp - Sq), (0, 0), (0, 0))
-        q = jnp.pad(q, qpad)
-    if Skp != Sk:
-        kpad = ((0, 0), (0, Skp - Sk), (0, 0), (0, 0))
-        k, v = jnp.pad(k, kpad), jnp.pad(v, kpad)
+    # head-major for the kernels: (B, S, H, hd) -> (B, H, S, hd), padded
+    q = jnp.pad(q.transpose(0, 2, 1, 3), ((0, 0), (0, 0), (0, Sqp - Sq), (0, 0)))
+    kpad = ((0, 0), (0, 0), (0, Skp - Sk), (0, 0))
+    k = jnp.pad(k.transpose(0, 2, 1, 3), kpad)
+    v = jnp.pad(v.transpose(0, 2, 1, 3), kpad)
     if bias is not None:
         bias = jnp.pad(bias.astype(jnp.float32),
                        ((0, 0), (0, Sqp - Sq), (0, Skp - Sk)),
@@ -291,4 +292,4 @@ def flash_mha(q, k, v, *, causal=True, window=None, scale=None,
         o = entry(q, k, v, bias)
     else:
         o = entry(q, k, v)
-    return o[:, :Sq] if Sqp != Sq else o
+    return o[:, :, :Sq].transpose(0, 2, 1, 3)

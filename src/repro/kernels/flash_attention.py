@@ -33,11 +33,14 @@ Kernels (S = q length, hd = head dim):
                           flash pass that makes the kernel usable under
                           ``jax.linearize`` (the curvature engine's J·v).
 
-BlockSpecs stage (blk_q x hd) query tiles and (blk_k x hd) key/value tiles
-into VMEM; the MXU sees (blk_q x hd) @ (hd x blk_k) matmuls with
-hardware-aligned tiles (blk_* multiples of 128 for f32/bf16). LSE/Δ ride in
-(B, H, S) layout with (1, 1, blk_q) blocks, the same layout the stock JAX
-flash kernels use for their l/m residuals.
+The kernels take head-major operands — q (B, H, Sq, hd), k/v (B, KV, Sk,
+hd) — so BlockSpecs stage (blk_q x hd) query tiles and (blk_k x hd)
+key/value tiles into VMEM with the head axis squeezed out of the two minor
+dimensions, as Mosaic requires; the MXU sees (blk_q x hd) @ (hd x blk_k)
+matmuls with hardware-aligned tiles (blk_* multiples of 128 for f32/bf16).
+LSE/Δ/t ride as (B, H, 1, S) rows with (1, blk_q) blocks.
+``kernels.flash_ad`` moves the model's (B, S, H, hd) activations into this
+layout once per attention call.
 """
 from __future__ import annotations
 
@@ -92,9 +95,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, *refs,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :]                                   # (blk_q, hd)
-    k = k_ref[0, :, 0, :]                                   # (blk_k, hd)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[...]                                   # (blk_q, hd)
+    k = k_ref[...]                                   # (blk_k, hd)
+    v = v_ref[...]
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                               # (blk_q, blk_k)
@@ -127,11 +130,11 @@ def _fa_kernel(q_ref, k_ref, v_ref, *refs,
     @pl.when(ki == n_k_blocks - 1)
     def _finish():
         norm = jnp.where(l_new <= 0.0, 1.0, l_new)
-        o_ref[0, :, 0, :] = (acc / norm).astype(o_ref.dtype)
+        o_ref[...] = (acc / norm).astype(o_ref.dtype)
         # per-row logsumexp residual; fully-masked rows get lse = 0 and the
         # downstream kernels mask their P entries explicitly anyway.
         m_fin = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-        lse_ref[0, 0, :] = (m_fin + jnp.log(norm))[:, 0]
+        lse_ref[0] = (m_fin + jnp.log(norm))[:, 0]
 
 
 def _recompute_p(q, k, lse, qi, ki, *, scale, causal, window, valid_len,
@@ -161,12 +164,12 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :]
-    k = k_ref[0, :, 0, :]
-    v = v_ref[0, :, 0, :]
-    do = do_ref[0, :, 0, :]
-    lse = lse_ref[0, 0, :]
-    delta = delta_ref[0, 0, :]
+    q = q_ref[...]
+    k = k_ref[...]
+    v = v_ref[...]
+    do = do_ref[...]
+    lse = lse_ref[0]
+    delta = delta_ref[0]
 
     p, _ = _recompute_p(q, k, lse, qi, ki, scale=scale, causal=causal,
                         window=window, valid_len=valid_len,
@@ -183,7 +186,7 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
 
     @pl.when(ki == n_k_blocks - 1)
     def _finish():
-        dq_ref[0, :, 0, :] = (acc_scr[...] * scale).astype(dq_ref.dtype)
+        dq_ref[...] = (acc_scr[...] * scale).astype(dq_ref.dtype)
 
 
 def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
@@ -202,12 +205,12 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    q = q_ref[0, :, 0, :]
-    k = k_ref[0, :, 0, :]
-    v = v_ref[0, :, 0, :]
-    do = do_ref[0, :, 0, :]
-    lse = lse_ref[0, 0, :]
-    delta = delta_ref[0, 0, :]
+    q = q_ref[...]
+    k = k_ref[...]
+    v = v_ref[...]
+    do = do_ref[...]
+    lse = lse_ref[0]
+    delta = delta_ref[0]
 
     p, _ = _recompute_p(q, k, lse, qi, ki, scale=scale, causal=causal,
                         window=window, valid_len=valid_len,
@@ -228,8 +231,8 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
 
     @pl.when(qi == n_q_blocks - 1)
     def _finish():
-        dk_ref[0, :, 0, :] = (dk_scr[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_scr[...].astype(dv_ref.dtype)
+        dk_ref[...] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _fa_jvp_kernel(q_ref, k_ref, v_ref, qt_ref, kt_ref, vt_ref, lse_ref,
@@ -247,13 +250,13 @@ def _fa_jvp_kernel(q_ref, k_ref, v_ref, qt_ref, kt_ref, vt_ref, lse_ref,
         g_scr[...] = jnp.zeros_like(g_scr)
         t_scr[...] = jnp.zeros_like(t_scr)
 
-    q = q_ref[0, :, 0, :]
-    k = k_ref[0, :, 0, :]
-    v = v_ref[0, :, 0, :]
-    qt = qt_ref[0, :, 0, :]
-    kt = kt_ref[0, :, 0, :]
-    vt = vt_ref[0, :, 0, :]
-    lse = lse_ref[0, 0, :]
+    q = q_ref[...]
+    k = k_ref[...]
+    v = v_ref[...]
+    qt = qt_ref[...]
+    kt = kt_ref[...]
+    vt = vt_ref[...]
+    lse = lse_ref[0]
 
     p, mask = _recompute_p(q, k, lse, qi, ki, scale=scale, causal=causal,
                            window=window, valid_len=valid_len,
@@ -276,14 +279,19 @@ def _fa_jvp_kernel(q_ref, k_ref, v_ref, qt_ref, kt_ref, vt_ref, lse_ref,
 
     @pl.when(ki == n_k_blocks - 1)
     def _finish():
-        g_ref[0, :, 0, :] = g_scr[...].astype(g_ref.dtype)
-        t_ref[0, 0, :] = t_scr[:, 0]
+        g_ref[...] = g_scr[...].astype(g_ref.dtype)
+        t_ref[0] = t_scr[:, 0]
 
 
 # --------------------------------------------------------------- wrappers --
+# Layout: q (B, H, Sq, hd), k/v (B, KV, Sk, hd), so every tile's two minor
+# dimensions are (blk, hd) — what Mosaic tiles ((8, 128) or the full dim).
+# Per-row statistics (lse, Δ, t) are (B, H, Sq) to callers and (B, H, 1, Sq)
+# to the kernels: a (1, blk_q) row block whose second-minor dim is the whole
+# (unit) axis.
 def _shapes(q, k, blk_q, blk_k):
-    B, Sq, H, hd = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    B, H, Sq, hd = q.shape
+    KV, Sk = k.shape[1], k.shape[2]
     G = H // KV
     blk_q = min(blk_q, Sq)
     blk_k = min(blk_k, Sk)
@@ -295,10 +303,35 @@ def _resolve_scale(scale, hd):
     return float(scale if scale is not None else 1.0 / (hd ** 0.5))
 
 
+def _q_spec(blk_q, hd, transposed_grid=False):
+    """(blk_q, hd) tile of a (B, H, Sq, hd) operand. ``transposed_grid``:
+    the dK/dV grid is (B, H, k, q)."""
+    if transposed_grid:
+        return pl.BlockSpec((None, None, blk_q, hd), lambda b, h, j, i: (b, h, i, 0))
+    return pl.BlockSpec((None, None, blk_q, hd), lambda b, h, i, j: (b, h, i, 0))
+
+
+def _kv_spec(blk_k, hd, G, transposed_grid=False):
+    """(blk_k, hd) tile of a (B, KV, Sk, hd) operand; query head h reads kv
+    head h // G (GQA)."""
+    if transposed_grid:
+        return pl.BlockSpec((None, None, blk_k, hd),
+                            lambda b, h, j, i: (b, h // G, j, 0))
+    return pl.BlockSpec((None, None, blk_k, hd),
+                        lambda b, h, i, j: (b, h // G, j, 0))
+
+
+def _row_spec(blk_q, transposed_grid=False):
+    """(1, blk_q) block of a (B, H, 1, Sq) per-row statistic."""
+    if transposed_grid:
+        return pl.BlockSpec((None, None, 1, blk_q), lambda b, h, j, i: (b, h, 0, i))
+    return pl.BlockSpec((None, None, 1, blk_q), lambda b, h, i, j: (b, h, 0, i))
+
+
 def _bias_spec(bias, blk_q, blk_k, transposed_grid=False):
     """BlockSpec for the optional (Bb, Sq, Sk) f32 additive-bias operand.
     Bb == 1 broadcasts over the batch in the index map (no materialized
-    copy). ``transposed_grid``: the dK/dV grid is (B, H, k, q)."""
+    copy)."""
     bb = bias.shape[0]
     if transposed_grid:
         return pl.BlockSpec((1, blk_q, blk_k),
@@ -307,10 +340,17 @@ def _bias_spec(bias, blk_q, blk_k, transposed_grid=False):
                         lambda b, h, i, j: (b if bb > 1 else 0, i, j))
 
 
+def _with_bias(in_specs, args, bias, blk_q, blk_k, transposed_grid=False):
+    if bias is None:
+        return in_specs, args
+    return (in_specs + [_bias_spec(bias, blk_q, blk_k, transposed_grid)],
+            args + (bias,))
+
+
 def flash_attention_fwd(q, k, v, *, causal=True, window=None, valid_len=None,
                         scale=None, blk_q=128, blk_k=128, interpret=False,
                         bias=None):
-    """q: (B,Sq,H,hd), k/v: (B,Sk,KV,hd) -> (o: (B,Sq,H,hd), lse: (B,H,Sq)).
+    """q: (B,H,Sq,hd), k/v: (B,KV,Sk,hd) -> (o: (B,H,Sq,hd), lse: (B,H,Sq)).
     ``bias``: optional (B|1, Sq, Sk) f32 additive logit bias (explicit
     masks: 0 attend / NEG_INF drop)."""
     B, Sq, Sk, H, hd, KV, G, blk_q, blk_k, nq, nk = _shapes(q, k, blk_q, blk_k)
@@ -320,26 +360,17 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, valid_len=None,
         valid_len=valid_len, blk_q=blk_q, blk_k=blk_k, n_k_blocks=nk,
         has_bias=bias is not None,
     )
-    in_specs = [
-        pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, i, j: (b, i, h, 0)),
-        pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, i, j: (b, j, h // G, 0)),
-        pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, i, j: (b, j, h // G, 0)),
-    ]
-    args = (q, k, v)
-    if bias is not None:
-        in_specs.append(_bias_spec(bias, blk_q, blk_k))
-        args = args + (bias,)
-    return pl.pallas_call(
+    in_specs, args = _with_bias(
+        [_q_spec(blk_q, hd), _kv_spec(blk_k, hd, G), _kv_spec(blk_k, hd, G)],
+        (q, k, v), bias, blk_q, blk_k)
+    o, lse = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, 1, blk_q), lambda b, h, i, j: (b, h, i)),
-        ),
+        out_specs=(_q_spec(blk_q, hd), _row_spec(blk_q)),
         out_shape=(
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, Sq), jnp.float32),
         ),
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),
@@ -348,12 +379,13 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, valid_len=None,
         ],
         interpret=interpret,
     )(*args)
+    return o, lse[:, :, 0]
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, valid_len=None,
                     scale=None, blk_q=128, blk_k=128, interpret=False,
                     bias=None):
-    """Forward only (serving path): q (B,Sq,H,hd), k/v (B,Sk,KV,hd) -> o."""
+    """Forward only (serving path): q (B,H,Sq,hd), k/v (B,KV,Sk,hd) -> o."""
     return flash_attention_fwd(
         q, k, v, causal=causal, window=window, valid_len=valid_len,
         scale=scale, blk_q=blk_q, blk_k=blk_k, interpret=interpret, bias=bias,
@@ -363,7 +395,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, valid_len=None,
 def flash_attention_dq(q, k, v, do, lse, delta, *, causal=True, window=None,
                        valid_len=None, scale=None, blk_q=128, blk_k=128,
                        interpret=False, bias=None):
-    """Backward dQ pass. lse/delta: (B,H,Sq). Returns dq (B,Sq,H,hd)."""
+    """Backward dQ pass. lse/delta: (B,H,Sq). Returns dq (B,H,Sq,hd)."""
     B, Sq, Sk, H, hd, KV, G, blk_q, blk_k, nq, nk = _shapes(q, k, blk_q, blk_k)
     scale = _resolve_scale(scale, hd)
     kernel = functools.partial(
@@ -371,23 +403,15 @@ def flash_attention_dq(q, k, v, do, lse, delta, *, causal=True, window=None,
         valid_len=valid_len, blk_q=blk_q, blk_k=blk_k, n_k_blocks=nk,
         has_bias=bias is not None,
     )
-    in_specs = [
-        pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, i, j: (b, i, h, 0)),
-        pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, i, j: (b, j, h // G, 0)),
-        pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, i, j: (b, j, h // G, 0)),
-        pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, i, j: (b, i, h, 0)),
-        pl.BlockSpec((1, 1, blk_q), lambda b, h, i, j: (b, h, i)),
-        pl.BlockSpec((1, 1, blk_q), lambda b, h, i, j: (b, h, i)),
-    ]
-    args = (q, k, v, do, lse, delta)
-    if bias is not None:
-        in_specs.append(_bias_spec(bias, blk_q, blk_k))
-        args = args + (bias,)
+    in_specs, args = _with_bias(
+        [_q_spec(blk_q, hd), _kv_spec(blk_k, hd, G), _kv_spec(blk_k, hd, G),
+         _q_spec(blk_q, hd), _row_spec(blk_q), _row_spec(blk_q)],
+        (q, k, v, do, lse[:, :, None], delta[:, :, None]), bias, blk_q, blk_k)
     return pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, i, j: (b, i, h, 0)),
+        out_specs=_q_spec(blk_q, hd),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, hd), jnp.float32)],
         interpret=interpret,
@@ -398,7 +422,7 @@ def flash_attention_dkv(q, k, v, do, lse, delta, *, causal=True, window=None,
                         valid_len=None, scale=None, blk_q=128, blk_k=128,
                         interpret=False, bias=None):
     """Backward dK/dV pass, per *query* head (the caller sums each GQA
-    group). Returns (dk_h, dv_h): (B,Sk,H,hd)."""
+    group). Returns (dk_h, dv_h): (B,H,Sk,hd)."""
     B, Sq, Sk, H, hd, KV, G, blk_q, blk_k, nq, nk = _shapes(q, k, blk_q, blk_k)
     scale = _resolve_scale(scale, hd)
     kernel = functools.partial(
@@ -406,31 +430,22 @@ def flash_attention_dkv(q, k, v, do, lse, delta, *, causal=True, window=None,
         valid_len=valid_len, blk_q=blk_q, blk_k=blk_k, n_q_blocks=nq,
         has_bias=bias is not None,
     )
-    in_specs = [
-        pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, j, i: (b, i, h, 0)),
-        pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, j, i: (b, j, h // G, 0)),
-        pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, j, i: (b, j, h // G, 0)),
-        pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, j, i: (b, i, h, 0)),
-        pl.BlockSpec((1, 1, blk_q), lambda b, h, j, i: (b, h, i)),
-        pl.BlockSpec((1, 1, blk_q), lambda b, h, j, i: (b, h, i)),
-    ]
-    args = (q, k, v, do, lse, delta)
-    if bias is not None:
-        in_specs.append(_bias_spec(bias, blk_q, blk_k, transposed_grid=True))
-        args = args + (bias,)
+    in_specs, args = _with_bias(
+        [_q_spec(blk_q, hd, True), _kv_spec(blk_k, hd, G, True),
+         _kv_spec(blk_k, hd, G, True), _q_spec(blk_q, hd, True),
+         _row_spec(blk_q, True), _row_spec(blk_q, True)],
+        (q, k, v, do, lse[:, :, None], delta[:, :, None]), bias, blk_q, blk_k,
+        transposed_grid=True)
     return pl.pallas_call(
         kernel,
         grid=(B, H, nk, nq),
         in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, j, i: (b, j, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, j, i: (b, j, h, 0)),
-        ),
+        out_specs=(_kv_spec(blk_k, hd, 1, True), _kv_spec(blk_k, hd, 1, True)),
         out_shape=(
             # per-q-head partials stay f32 so the GQA group-sum outside the
             # kernel accumulates at full precision even for bf16 models
-            jax.ShapeDtypeStruct((B, Sk, H, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, Sk, H, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sk, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sk, hd), jnp.float32),
         ),
         scratch_shapes=[
             pltpu.VMEM((blk_k, hd), jnp.float32),
@@ -443,7 +458,7 @@ def flash_attention_dkv(q, k, v, do, lse, delta, *, causal=True, window=None,
 def flash_attention_jvp(q, k, v, qt, kt, vt, lse, *, causal=True, window=None,
                         valid_len=None, scale=None, blk_q=128, blk_k=128,
                         interpret=False, bias=None):
-    """Tangent pass: returns (g: (B,Sq,H,hd), t: (B,H,Sq)) with
+    """Tangent pass: returns (g: (B,H,Sq,hd), t: (B,H,Sq)) with
     g_i = Σ_j P_ij (Ṡ_ij v_j + v̇_j) and t_i = Σ_j P_ij Ṡ_ij; the caller
     forms ȯ = g − t ∘ o (and l̇se = t)."""
     B, Sq, Sk, H, hd, KV, G, blk_q, blk_k, nq, nk = _shapes(q, k, blk_q, blk_k)
@@ -453,30 +468,19 @@ def flash_attention_jvp(q, k, v, qt, kt, vt, lse, *, causal=True, window=None,
         valid_len=valid_len, blk_q=blk_q, blk_k=blk_k, n_k_blocks=nk,
         has_bias=bias is not None,
     )
-    in_specs = [
-        pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, i, j: (b, i, h, 0)),
-        pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, i, j: (b, j, h // G, 0)),
-        pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, i, j: (b, j, h // G, 0)),
-        pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, i, j: (b, i, h, 0)),
-        pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, i, j: (b, j, h // G, 0)),
-        pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, i, j: (b, j, h // G, 0)),
-        pl.BlockSpec((1, 1, blk_q), lambda b, h, i, j: (b, h, i)),
-    ]
-    args = (q, k, v, qt, kt, vt, lse)
-    if bias is not None:
-        in_specs.append(_bias_spec(bias, blk_q, blk_k))
-        args = args + (bias,)
-    return pl.pallas_call(
+    in_specs, args = _with_bias(
+        [_q_spec(blk_q, hd), _kv_spec(blk_k, hd, G), _kv_spec(blk_k, hd, G),
+         _q_spec(blk_q, hd), _kv_spec(blk_k, hd, G), _kv_spec(blk_k, hd, G),
+         _row_spec(blk_q)],
+        (q, k, v, qt, kt, vt, lse[:, :, None]), bias, blk_q, blk_k)
+    g, t = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, 1, blk_q), lambda b, h, i, j: (b, h, i)),
-        ),
+        out_specs=(_q_spec(blk_q, hd), _row_spec(blk_q)),
         out_shape=(
-            jax.ShapeDtypeStruct((B, Sq, H, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, Sq), jnp.float32),
         ),
         scratch_shapes=[
             pltpu.VMEM((blk_q, hd), jnp.float32),
@@ -484,3 +488,4 @@ def flash_attention_jvp(q, k, v, qt, kt, vt, lse, *, causal=True, window=None,
         ],
         interpret=interpret,
     )(*args)
+    return g, t[:, :, 0]

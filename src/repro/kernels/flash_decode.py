@@ -26,15 +26,15 @@ the O(H·W) logits + two-pass softmax traffic is what this kernel removes).
 
 Kernels:
 
-  * ``_fd_kernel``       — dense rolling cache. Grid (B, KV, n_splits,
+  * ``_fd_kernel``       — dense rolling cache. Grid (B, n_splits,
                            blocks_per_split): the innermost axis reduces
                            sequentially into VMEM scratch (the PR 4
-                           m/l/acc recurrence), the n_splits axis is
-                           embarrassingly parallel and each split writes its
-                           own (o, m, l). GQA is handled by shaping q as
-                           (B, KV, G, hd) — all G query heads of one kv head
-                           share the K/V tiles of a grid cell.
-  * ``_fd_paged_kernel`` — paged cache. Grid (B, KV, max_pages) with the
+                           m/l/acc recurrence, one row set per kv head), the
+                           n_splits axis is embarrassingly parallel and each
+                           split writes its own (o, m, l). GQA is handled by
+                           shaping q as (B, KV, G, hd) — all G query heads
+                           of one kv head share that head's K/V tile.
+  * ``_fd_paged_kernel`` — paged cache. Grid (B, max_pages) with the
                            page table as a *scalar-prefetch* operand: the
                            K/V BlockSpec index maps dereference
                            ``page_table[b, j]`` to pick the physical pool
@@ -47,8 +47,8 @@ Kernels:
 Off-TPU both kernels run in interpret mode (how this repo validates them);
 the wall-clock caveat of EXPERIMENTS.md §Perf pair F applies — the honest
 CPU signal is the XLA peak-memory column of ``benchmarks/decode_bench.py``.
-TPU layout note: the per-split stats outputs are (..., n_splits, G) with G
-in the lane dimension; for small G this under-fills the 128-lane tile, but
+TPU layout note: the per-split stats outputs are (..., n_splits, KV, G)
+with G in the lane dimension; for small G this under-fills the 128-lane tile, but
 the stats are O(B·H·n_splits) — noise next to the K/V traffic.
 """
 from __future__ import annotations
@@ -108,9 +108,14 @@ def paged_bias(page_table, seq_len, page_size, *, window=None):
 
 
 # ------------------------------------------------------------------ kernels --
+# Each grid cell stages one KV block for *all* kv heads — a (blk, KV, hd)
+# tile of the cache's own (…, W, KV, hd) layout, whose two minor dims are
+# whole array dims as Mosaic requires — and loops over the kv heads inside.
+# So the cache is read in place (no head-major copy) and every block is
+# DMA'd once for all heads.
 def _fd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
-               m_scr, l_scr, acc_scr, *, scale, n_inner):
-    i = pl.program_id(3)
+               m_scr, l_scr, acc_scr, *, scale, n_inner, n_kv):
+    i = pl.program_id(2)
 
     @pl.when(i == 0)
     def _init():
@@ -118,60 +123,63 @@ def _fd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0]                                          # (G, hd)
-    k = k_ref[0, :, 0, :]                                    # (blk_k, hd)
-    v = v_ref[0, :, 0, :]
-    bias = bias_ref[0]                                       # (blk_k,)
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale + bias[None, :]                                # (G, blk_k)
+    bias = bias_ref[...]                                     # (1, blk_k)
+    for h in range(n_kv):
+        q = q_ref[h]                                         # (G, hd)
+        k = k_ref[:, h, :]                                   # (blk_k, hd)
+        v = v_ref[:, h, :]
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale + bias                                     # (G, blk_k)
 
-    m_prev = m_scr[...]                                      # (G, 1)
-    l_prev = l_scr[...]
-    m_cur = jnp.max(logits, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-    # masked entries carry bias <= NEG_INF, so exp underflows to exact 0
-    p = jnp.exp(logits - m_safe)
-    alpha = jnp.exp(jnp.where(m_prev <= NEG_INF / 2, NEG_INF, m_prev - m_safe))
-    l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    acc = acc_scr[...] * alpha + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-    acc_scr[...] = acc
+        m_prev = m_scr[h]                                    # (G, 1)
+        l_prev = l_scr[h]
+        m_cur = jnp.max(logits, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+        # masked entries carry bias <= NEG_INF, so exp underflows to exact 0
+        p = jnp.exp(logits - m_safe)
+        alpha = jnp.exp(jnp.where(m_prev <= NEG_INF / 2, NEG_INF,
+                                  m_prev - m_safe))
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[h] = m_new
+        l_scr[h] = l_new
 
     @pl.when(i == n_inner - 1)
     def _finish():
-        norm = jnp.where(l_new <= 0.0, 1.0, l_new)
-        o_ref[0, 0, 0] = (acc / norm).astype(o_ref.dtype)
-        m_ref[0, 0, 0] = m_new[:, 0]
-        l_ref[0, 0, 0] = l_new[:, 0]
+        l_fin = l_scr[...]                                   # (KV, G, 1)
+        norm = jnp.where(l_fin <= 0.0, 1.0, l_fin)
+        o_ref[...] = (acc_scr[...] / norm).astype(o_ref.dtype)
+        m_ref[...] = m_scr[...][..., 0]
+        l_ref[...] = l_fin[..., 0]
 
 
 def _fd_paged_kernel(tbl_ref, q_ref, k_ref, v_ref, bias_ref,
-                     o_ref, m_ref, l_ref, *, scale):
+                     o_ref, m_ref, l_ref, *, scale, n_kv):
     # one page == one split: single-shot softmax, no scratch recurrence
-    q = q_ref[0, 0]                                          # (G, hd)
-    k = k_ref[0, :, 0, :]                                    # (ps, hd)
-    v = v_ref[0, :, 0, :]
-    bias = bias_ref[0]                                       # (ps,)
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale + bias[None, :]
-    m = jnp.max(logits, axis=1, keepdims=True)               # (G, 1)
-    m_safe = jnp.where(m <= NEG_INF / 2, 0.0, m)
-    p = jnp.exp(logits - m_safe)
-    l = jnp.sum(p, axis=1, keepdims=True)
-    o = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) / jnp.where(l <= 0.0, 1.0, l)
-    o_ref[0, 0, 0] = o.astype(o_ref.dtype)
-    m_ref[0, 0, 0] = m[:, 0]
-    l_ref[0, 0, 0] = l[:, 0]
+    bias = bias_ref[...]                                     # (1, ps)
+    for h in range(n_kv):
+        q = q_ref[h]                                         # (G, hd)
+        k = k_ref[:, h, :]                                   # (ps, hd)
+        v = v_ref[:, h, :]
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale + bias
+        m = jnp.max(logits, axis=1, keepdims=True)           # (G, 1)
+        m_safe = jnp.where(m <= NEG_INF / 2, 0.0, m)
+        p = jnp.exp(logits - m_safe)
+        l = jnp.sum(p, axis=1, keepdims=True)
+        o = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) / jnp.where(l <= 0.0, 1.0, l)
+        o_ref[h] = o.astype(o_ref.dtype)
+        m_ref[h] = m[:, 0]
+        l_ref[h] = l[:, 0]
 
 
 # ------------------------------------------------------------ split combine --
@@ -234,37 +242,54 @@ def flash_decode(q, k, v, bias, *, scale=None, blk_k=128, n_splits=8,
     n_inner = nk // ns
     qg = q.reshape(B, KV, G, hd)
 
-    kernel = functools.partial(_fd_kernel, scale=scale, n_inner=n_inner)
+    kernel = functools.partial(_fd_kernel, scale=scale, n_inner=n_inner,
+                               n_kv=KV)
     o, m, l = pl.pallas_call(
         kernel,
-        grid=(B, KV, ns, n_inner),
+        grid=(B, ns, n_inner),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, s, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd),
-                         lambda b, h, s, i: (b, s * n_inner + i, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd),
-                         lambda b, h, s, i: (b, s * n_inner + i, h, 0)),
-            pl.BlockSpec((1, blk_k), lambda b, h, s, i: (b, s * n_inner + i)),
+            pl.BlockSpec((None, KV, G, hd), lambda b, s, i: (b, 0, 0, 0)),
+            pl.BlockSpec((None, blk_k, KV, hd),
+                         lambda b, s, i: (b, s * n_inner + i, 0, 0)),
+            pl.BlockSpec((None, blk_k, KV, hd),
+                         lambda b, s, i: (b, s * n_inner + i, 0, 0)),
+            pl.BlockSpec((None, 1, blk_k),
+                         lambda b, s, i: (b, 0, s * n_inner + i)),
         ],
-        out_specs=(
-            pl.BlockSpec((1, 1, 1, G, hd), lambda b, h, s, i: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, s, i: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, s, i: (b, h, s, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((B, KV, ns, G, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, KV, ns, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, ns, G), jnp.float32),
-        ),
+        out_specs=_split_out_specs(KV, G, hd, lambda b, s, i: (b, s)),
+        out_shape=_split_out_shapes(B, ns, KV, G, hd, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, k, v, bias)
-    og, mg, lg = combine_splits(o.astype(jnp.float32), m, l)
-    og = og.astype(q.dtype)
+    )(qg, k, v, bias[:, None])
+    return _combine(o, m, l, q.dtype, return_stats)
+
+
+def _split_out_specs(KV, G, hd, cell):
+    """(o, m, l) partial blocks, one per (sequence, split): all kv heads."""
+    return (
+        pl.BlockSpec((None, None, KV, G, hd), lambda *g: (*cell(*g), 0, 0, 0)),
+        pl.BlockSpec((None, None, KV, G), lambda *g: (*cell(*g), 0, 0)),
+        pl.BlockSpec((None, None, KV, G), lambda *g: (*cell(*g), 0, 0)),
+    )
+
+
+def _split_out_shapes(B, ns, KV, G, hd, dtype):
+    return (
+        jax.ShapeDtypeStruct((B, ns, KV, G, hd), dtype),
+        jax.ShapeDtypeStruct((B, ns, KV, G), jnp.float32),
+        jax.ShapeDtypeStruct((B, ns, KV, G), jnp.float32),
+    )
+
+
+def _combine(o, m, l, dtype, return_stats):
+    """Split-major kernel partials -> combine_splits' (B, KV, S, ...) order."""
+    og, mg, lg = combine_splits(o.astype(jnp.float32).transpose(0, 2, 1, 3, 4),
+                                m.transpose(0, 2, 1, 3), l.transpose(0, 2, 1, 3))
+    og = og.astype(dtype)
     return (og, mg, lg) if return_stats else og
 
 
@@ -287,34 +312,24 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, bias, *, scale=None,
     scale = float(scale if scale is not None else 1.0 / (hd ** 0.5))
     qg = q.reshape(B, KV, G, hd)
 
-    kernel = functools.partial(_fd_paged_kernel, scale=scale)
+    kernel = functools.partial(_fd_paged_kernel, scale=scale, n_kv=KV)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, KV, maxp),
+        grid=(B, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, tbl: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, j, tbl: (jnp.maximum(tbl[b, j], 0), 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, j, tbl: (jnp.maximum(tbl[b, j], 0), 0, h, 0)),
-            pl.BlockSpec((1, ps), lambda b, h, j, tbl: (b, j)),
+            pl.BlockSpec((None, KV, G, hd), lambda b, j, tbl: (b, 0, 0, 0)),
+            pl.BlockSpec((None, ps, KV, hd),
+                         lambda b, j, tbl: (jnp.maximum(tbl[b, j], 0), 0, 0, 0)),
+            pl.BlockSpec((None, ps, KV, hd),
+                         lambda b, j, tbl: (jnp.maximum(tbl[b, j], 0), 0, 0, 0)),
+            pl.BlockSpec((None, 1, ps), lambda b, j, tbl: (b, 0, j)),
         ],
-        out_specs=(
-            pl.BlockSpec((1, 1, 1, G, hd), lambda b, h, j, tbl: (b, h, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, j, tbl: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, j, tbl: (b, h, j, 0)),
-        ),
+        out_specs=_split_out_specs(KV, G, hd, lambda b, j, tbl: (b, j)),
     )
     o, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((B, KV, maxp, G, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, KV, maxp, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, maxp, G), jnp.float32),
-        ),
+        out_shape=_split_out_shapes(B, maxp, KV, G, hd, q.dtype),
         interpret=interpret,
-    )(page_table, qg, k_pool, v_pool, bias)
-    og, mg, lg = combine_splits(o.astype(jnp.float32), m, l)
-    og = og.astype(q.dtype)
-    return (og, mg, lg) if return_stats else og
+    )(page_table, qg, k_pool, v_pool, bias[:, None])
+    return _combine(o, m, l, q.dtype, return_stats)

@@ -69,6 +69,12 @@ def _default_interpret():
     return jax.default_backend() != "tpu"
 
 
+def _hm(x):
+    """(B, S, H, hd) <-> (B, H, S, hd): the model's layout to the kernels'
+    head-major one (its own inverse)."""
+    return x.transpose(0, 2, 1, 3)
+
+
 def flash_attention(q, k, v, *, causal=True, window=None, blk_q=128, blk_k=128,
                     interpret=None, bias=None):
     """Fully differentiable flash attention (training + serving path).
@@ -97,10 +103,11 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, valid_len=None,
     """Raw forward kernel: (o, lse) with lse: (B,H,S) the per-row logsumexp
     residual the backward/JVP kernels consume (non-differentiable wrapper)."""
     interpret = _default_interpret() if interpret is None else interpret
-    return fa.flash_attention_fwd(
-        q, k, v, causal=causal, window=window, valid_len=valid_len,
-        blk_q=blk_q, blk_k=blk_k, interpret=interpret, bias=bias,
-    )
+    o, lse = fa.flash_attention_fwd(
+        _hm(q), _hm(k), _hm(v), causal=causal, window=window,
+        valid_len=valid_len, blk_q=blk_q, blk_k=blk_k, interpret=interpret,
+        bias=bias)
+    return _hm(o), lse
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -112,9 +119,11 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
     Pallas dQ pass, the Pallas dK/dV pass, and the GQA group-sum. Same
     implementation jax.grad executes (flash_ad.flash_bwd_passes)."""
     interpret = _default_interpret() if interpret is None else interpret
-    return flash_ad.flash_bwd_passes(
-        q, k, v, o, lse, do, causal=causal, window=window, bias=bias,
-        valid_len=valid_len, blk_q=blk_q, blk_k=blk_k, interpret=interpret)
+    grads = flash_ad.flash_bwd_passes(
+        _hm(q), _hm(k), _hm(v), _hm(o), lse, _hm(do), causal=causal,
+        window=window, bias=bias, valid_len=valid_len, blk_q=blk_q,
+        blk_k=blk_k, interpret=interpret)
+    return tuple(_hm(g) for g in grads)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -126,9 +135,11 @@ def flash_attention_jvp(q, k, v, o, lse, qt, kt, vt, *, causal=True,
     block matmuls per tile: Q̇Kᵀ + QK̇ᵀ against the recomputed P). Same
     implementation jax.linearize executes (flash_ad.flash_jvp_pass)."""
     interpret = _default_interpret() if interpret is None else interpret
-    return flash_ad.flash_jvp_pass(
-        q, k, v, o, lse, qt, kt, vt, causal=causal, window=window, bias=bias,
-        valid_len=valid_len, blk_q=blk_q, blk_k=blk_k, interpret=interpret)
+    ot, lset = flash_ad.flash_jvp_pass(
+        _hm(q), _hm(k), _hm(v), _hm(o), lse, _hm(qt), _hm(kt), _hm(vt),
+        causal=causal, window=window, bias=bias, valid_len=valid_len,
+        blk_q=blk_q, blk_k=blk_k, interpret=interpret)
+    return _hm(ot), lset
 
 
 @functools.partial(jax.jit, static_argnames=(

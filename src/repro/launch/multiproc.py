@@ -13,16 +13,15 @@ The parent re-executes its own command line N times with
 ``REPRO_MULTIPROC_*`` set; each child calls :func:`initialize_from_env`
 BEFORE any jax device use, which points ``jax.distributed.initialize`` at a
 local TCP coordinator and selects the gloo CPU collective backend. Each
-child is pinned to ONE CPU device (``XLA_FLAGS`` below) so the global
-device count equals the process count and ``launch.mesh.make_data_mesh``
-builds an N-way pure data-parallel mesh.
+child is pinned to the CPU backend (``JAX_PLATFORMS``) and to ONE CPU
+device (``XLA_FLAGS`` below), so the global device count equals the process
+count and ``launch.mesh.make_data_mesh`` builds an N-way pure data-parallel
+mesh — and no child reaches for an accelerator the parent may hold.
 
-On a TPU pod the same entry point applies: the pod runtime launches one
-process per host itself, so skip :func:`spawn` and call
-``jax.distributed.initialize()`` with no arguments (auto-detected
-coordinator); everything downstream — mesh construction over global
-devices, :func:`shard_batch` / :func:`replicate` placement, primary-only
-logging — is identical.
+On a TPU host no spawning is needed: one process drives all its chips, and
+``launch/train.py`` takes the data-parallel step over them whenever JAX
+sees more than one device. Downstream of the mesh — :func:`shard_batch` /
+:func:`replicate` placement, primary-only logging — the code is the same.
 
 Placement invariants (multi-process jit refuses to reshard across
 processes, so inputs must arrive with their final global sharding):
@@ -70,6 +69,14 @@ EXIT_WATCHDOG = 87
 _CHILD_XLA_FLAGS = "--xla_force_host_platform_device_count=1"
 
 
+def _child_env(env: dict | None) -> dict:
+    """The children's base environment: the CPU backend, one device each."""
+    base = dict(os.environ if env is None else env)
+    base["JAX_PLATFORMS"] = "cpu"
+    base["XLA_FLAGS"] = _CHILD_XLA_FLAGS
+    return base
+
+
 def active() -> bool:
     """True in a child process spawned by :func:`spawn`."""
     return ENV_NUM in os.environ
@@ -95,8 +102,7 @@ def spawn(
     if any child exits non-zero.
     """
     coord = f"127.0.0.1:{_free_port()}"
-    base = dict(os.environ if env is None else env)
-    base["XLA_FLAGS"] = _CHILD_XLA_FLAGS
+    base = _child_env(env)
     procs = []
     for pid in range(num_processes):
         child_env = dict(base)
@@ -227,8 +233,7 @@ def spawn_supervised(
     if heartbeat_dir is None:
         heartbeat_dir = tempfile.mkdtemp(prefix="repro-hb-")
     os.makedirs(heartbeat_dir, exist_ok=True)
-    base = dict(os.environ if env is None else env)
-    base["XLA_FLAGS"] = _CHILD_XLA_FLAGS
+    base = _child_env(env)
     base[ENV_HEARTBEAT_DIR] = heartbeat_dir
 
     last_failure = "never launched"

@@ -31,6 +31,7 @@ from ..configs import ARCH_IDS, get_config, get_smoke_config
 from ..data import lm_batch
 from ..models import build_model
 from ..models.kv_paged import pages_needed, release_slots
+from .cache import enable_compile_cache
 
 
 def serve(arch: str, *, smoke=True, batch_size=4, prompt_len=16, gen_len=16,
@@ -220,6 +221,7 @@ def serve_continuous(arch: str, *, smoke=True, batch_size=4, n_requests=8,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=list(ARCH_IDS), default="qwen2-1.5b")
     ap.add_argument("--smoke", action="store_true", default=True)

@@ -7,13 +7,15 @@ Runs the distributed HF optimizer (or a first-order baseline) on synthetic
 LM data, with checkpointing and metric logging. ``--smoke`` selects the
 reduced config (CPU-runnable); without it the full config is used (TPU).
 
-``--num-processes N`` (N > 1) re-launches this same command as N
-coordinated processes (launch/multiproc.py) and runs the explicit
-shard_map data-parallel HF step (core/distributed.py) over an N-way
-"data" mesh — one CPU device per process locally, the pod runtime's
-process set on TPU. ``--overlap`` turns on the overlapped-collective
-schedule (HFConfig.overlap: double-buffered s-step cycles, hidden
-gradient reduce, paired line search).
+Whenever JAX sees more than one device — the chips of one TPU host driven
+by this one process, or the processes of a ``--num-processes N`` run — the
+step is the explicit shard_map data-parallel HF step (core/distributed.py)
+over a "data" mesh of all of them. ``--num-processes N`` (N > 1)
+re-launches this same command as N coordinated CPU processes
+(launch/multiproc.py, gloo collectives, one device each): the harness that
+makes the collectives cross a real process boundary. ``--overlap`` turns
+on the overlapped-collective schedule (HFConfig.overlap: double-buffered
+s-step cycles, hidden gradient reduce, paired line search).
 """
 from __future__ import annotations
 
@@ -37,6 +39,7 @@ from ..obs import trace as trace_mod
 from ..optim import make_optimizer
 from . import faults as faults_mod
 from . import multiproc
+from .cache import enable_compile_cache
 from .mesh import make_data_mesh
 
 
@@ -62,7 +65,6 @@ def train(
     overlap: bool = False,
     nc_mode: str = "truncate",
     strict_descent: bool = False,
-    distributed: bool = False,
     ckpt_dir: str | None = None,
     ckpt_every: int = 0,
     telemetry_dir: str | None = None,
@@ -83,10 +85,10 @@ def train(
         overlap=overlap, nc_mode=nc_mode, strict_descent=strict_descent,
     )
     mesh = None
-    if distributed:
-        # Every process builds the SAME global mesh (global device list)
-        # and the same batch/params from the same PRNG; only the device_put
-        # placement differs per process.
+    if len(jax.devices()) > 1:
+        # Data parallelism over every global device. Every process builds
+        # the SAME global mesh and the same batch/params from the same PRNG;
+        # only the device_put placement differs per process.
         mesh = make_data_mesh()
         n_shards = mesh.shape["data"]
         if batch_size % n_shards != 0:
@@ -216,6 +218,7 @@ def train(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=list(ARCH_IDS), default="qwen2-1.5b")
     ap.add_argument("--smoke", action="store_true", default=True)
@@ -269,8 +272,9 @@ def main():
                     help="spawn N coordinated processes (jax.distributed, "
                          "gloo CPU collectives, 1 device each) and run the "
                          "explicit shard_map data-parallel step over an "
-                         "N-way data mesh; on a TPU pod the runtime spawns "
-                         "processes itself — see launch/multiproc.py")
+                         "N-way data mesh; a CPU harness — on a TPU host "
+                         "one process drives every chip, see "
+                         "launch/multiproc.py")
     ap.add_argument("--nc-mode", default="truncate",
                     choices=["truncate", "escape"],
                     help="negative-curvature policy: 'truncate' (passive "
@@ -343,7 +347,6 @@ def main():
         overlap=args.overlap,
         nc_mode=args.nc_mode,
         strict_descent=args.strict_descent,
-        distributed=multiproc.active(),
         ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
         telemetry_dir=args.telemetry_dir,
         watchdog_s=args.watchdog_s,

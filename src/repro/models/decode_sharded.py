@@ -22,7 +22,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..kernels import ops as kops
@@ -73,11 +72,11 @@ def sharded_decode_attend(p, x, t, cache: KVCache, cfg, mesh, *, axis="model",
     k = apply_rope(k, pos_t, rope_fraction=cfg.rope_fraction, theta=cfg.rope_theta)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(None, axis, None, None), P(None, axis, None, None), P(axis)),
         out_specs=(P(), P(None, axis, None, None), P(None, axis, None, None), P(axis)),
-        check_rep=False,  # pallas_call has no replication rule
+        check_vma=False,  # the kernel's out_shape declares no vma
     )
     def attend(q, k_new, v_new, k_sh, v_sh, pos_sh):
         # local slot index of the global rolling slot t % W, if it lands here

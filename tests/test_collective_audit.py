@@ -8,8 +8,8 @@ auditable at two levels:
   * STATIC — ``jaxpr_collective_counts`` walks the traced step and counts
     psum-family equations, split into unconditionally-executed ("top") vs
     inside-a-while-body ("while_body") regions. Pure data parallelism means
-    the ONLY collectives are all-reduces (psum/psum2 — pmean lowers to
-    psum2): no all-gathers or all-to-alls of model state, for every
+    the ONLY collectives are all-reduces (pmean binds one psum per reduced
+    leaf): no all-gathers or all-to-alls of model state, for every
     solver × s-step × curvature combo.
   * EXECUTED — ``count_executed`` tallies each preduce tag once per actual
     execution (while_loop trips included), which must reconcile with the
@@ -40,29 +40,31 @@ from benchmarks.comm_model import (hf_sstep_syncs_per_iteration,
 K = 8  # with cg_tol=0 the CG-family solves run to truncation/max_iters
 
 # solver × s-step × curvature grid. `static`: the audited (top, while_body)
-# psum2 equation counts — a deterministic fingerprint of the schedule; if a
-# change here is INTENTIONAL (a reduce added/removed/moved), update the
-# table and EXPERIMENTS.md §Perf pair I together.
+# psum equation counts — a deterministic fingerprint of the schedule. pmean
+# binds one psum per leaf, so a scalar reduce counts 1 and a reduce of the
+# 4-leaf MLP's params/gradient/product counts 4. If a change here is
+# INTENTIONAL (a reduce added/removed/moved), update the table and
+# EXPERIMENTS.md §Perf pair I together.
 COMBOS = {
     "hessian_cg_s1": dict(solver="hessian_cg", s=1, basis="monomial",
-                          overlap=False, curv="linearize", static=(5, 3)),
+                          overlap=False, curv="linearize", static=(11, 6)),
     "hessian_cg_s2": dict(solver="hessian_cg", s=2, basis="monomial",
-                          overlap=False, curv="linearize", static=(7, 7)),
+                          overlap=False, curv="linearize", static=(16, 16)),
     "hessian_cg_s2_overlap": dict(solver="hessian_cg", s=2, basis="monomial",
                                   overlap=True, curv="linearize",
-                                  static=(7, 12)),
+                                  static=(16, 27)),
     "hessian_cg_s2_chunked": dict(solver="hessian_cg", s=2, basis="monomial",
                                   overlap=False, curv="chunked",
-                                  static=(7, 4)),
+                                  static=(16, 13)),
     "gn_cg_s1": dict(solver="gn_cg", s=1, basis="monomial",
-                     overlap=False, curv="linearize", static=(6, 2)),
+                     overlap=False, curv="linearize", static=(12, 5)),
     "gn_cg_s4_newton": dict(solver="gn_cg", s=4, basis="newton",
-                            overlap=False, curv="linearize", static=(11, 6)),
+                            overlap=False, curv="linearize", static=(32, 21)),
     "bicgstab_s1": dict(solver="bicgstab", s=1, basis="monomial",
-                        overlap=False, curv="linearize", static=(5, 5)),
+                        overlap=False, curv="linearize", static=(11, 11)),
     "bicgstab_s2_newton": dict(solver="bicgstab", s=2, basis="newton",
                                overlap=False, curv="linearize",
-                               static=(23, 13)),
+                               static=(56, 31)),
 }
 
 
@@ -91,12 +93,12 @@ def test_static_schedule_is_all_reduce_only(name, setup):
     cfg, step = _make_step(model, mesh, spec)
     jaxpr = jax.make_jaxpr(step)(params, hf_init(params, cfg), data)
     counts = jaxpr_collective_counts(jaxpr.jaxpr)
-    # Pure data parallelism: all-reduces only (pmean → psum2), never an
+    # Pure data parallelism: all-reduces only (pmean → psum), never an
     # all-gather/all-to-all of model state — in ANY region.
     prims = set(counts["top"]) | set(counts["while_body"])
-    assert prims <= {"psum", "psum2"}, (name, counts)
+    assert prims <= {"psum"}, (name, counts)
     assert sum(counts["top"].values()) > 0, name
-    assert (counts["top"]["psum2"], counts["while_body"]["psum2"]) == \
+    assert (counts["top"]["psum"], counts["while_body"]["psum"]) == \
         spec["static"], (name, counts)
 
 

@@ -7,12 +7,16 @@ Checks:
   * the HLO of the shard_map step contains exactly the paper's collective
     schedule (all-reduces for grad + HVPs + line-search, nothing else)
   * sharding rules produce valid, divisible PartitionSpecs for every arch
+  * launch.train.train takes the data-parallel step whenever JAX sees more
+    than one device, and matches the 1-device losses
 """
+import json
 import os
 import subprocess
 import sys
 import textwrap
 
+import numpy as np
 import pytest
 
 SCRIPT = textwrap.dedent("""
@@ -81,6 +85,46 @@ def test_shard_map_hf_matches_single_device():
                        text=True, env=env, cwd=os.path.dirname(os.path.dirname(__file__)))
     assert r.returncode == 0, r.stdout + r.stderr
     assert "OK" in r.stdout
+
+
+TRAIN_SCRIPT = textwrap.dedent("""
+    import json, os, sys
+    os.environ["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={sys.argv[1]}")
+    import jax
+    from repro.launch.train import train
+
+    params, _, hist = train(
+        "qwen2-1.5b", smoke=True, solver="gn_cg", steps=2, batch_size=4,
+        seq_len=32, hvp_batch_frac=1.0, log_fn=lambda *a, **k: None)
+    leaf = jax.tree_util.tree_leaves(params)[0]
+    print(json.dumps({
+        "param_devices": len(leaf.sharding.device_set),
+        "losses": [h["loss"] for h in hist] + [hist[-1]["loss_new"]],
+    }))
+""")
+
+
+def _train_on(n_devices):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", TRAIN_SCRIPT, str(n_devices)],
+                       capture_output=True, text=True, env=env,
+                       cwd=os.path.dirname(os.path.dirname(__file__)))
+    assert r.returncode == 0, r.stdout + r.stderr
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def test_train_takes_data_parallel_step_over_all_devices():
+    """One process that sees 4 devices trains data-parallel over all of them
+    (params replicated on the 4-device mesh), and its losses match the
+    1-device run. The curvature batch is the whole batch, so both take the
+    same Gauss-Newton system."""
+    dp, one = _train_on(4), _train_on(1)
+    assert dp["param_devices"] == 4
+    assert one["param_devices"] == 1
+    np.testing.assert_allclose(dp["losses"], one["losses"], rtol=1e-5)
 
 
 SHARDING_SCRIPT = textwrap.dedent("""

@@ -180,7 +180,6 @@ class TestWatchdog:
         left outstanding), and tracing outside the context bakes nothing."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = jax.make_mesh((len(jax.devices()),), ("data",))
@@ -200,7 +199,7 @@ class TestWatchdog:
         try:
             def f(x):
                 return collectives.preduce(x, "data", tag="loss")
-            sm = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
+            sm = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
             out = jax.jit(sm)(jnp.arange(float(len(jax.devices()))))
             jax.block_until_ready(out)
         finally:

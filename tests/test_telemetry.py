@@ -97,7 +97,7 @@ def test_zero_cost_when_disabled(setup, tmp_path):
     assert "callback" not in str(jx_off)
     c_off = jaxpr_collective_counts(jx_off.jaxpr)
     # hessian_cg_s1 fingerprint from tests/test_collective_audit.py COMBOS
-    assert (c_off["top"]["psum2"], c_off["while_body"]["psum2"]) == (5, 3)
+    assert (c_off["top"]["psum"], c_off["while_body"]["psum"]) == (11, 6)
 
     with telemetry.Telemetry(str(tmp_path)) as sink:
         with telemetry.install(sink):
@@ -106,7 +106,7 @@ def test_zero_cost_when_disabled(setup, tmp_path):
                                             data)
     assert "callback" in str(jx_on)
     c_on = jaxpr_collective_counts(jx_on.jaxpr)
-    assert (c_on["top"]["psum2"], c_on["while_body"]["psum2"]) == (5, 3)
+    assert (c_on["top"]["psum"], c_on["while_body"]["psum"]) == (11, 6)
 
 
 # ------------------------------------------------------ event content --
