@@ -250,11 +250,11 @@ def preduce(tree: Any, axes: Sequence[str] | str, tag: str = "reduce"):
         if _s is not None:
             _s.collective_begin(_t, _l)
 
-    def _end(_, _s=sink, _w=wd, _t=tag, _l=label):
+    def _end(step, _, _s=sink, _w=wd, _t=tag, _l=label):
         if _w is not None:
             _w.disarm(_t)
         if _s is not None:
-            _s.collective_end(_t, _l)
+            _s.collective_end(_t, _l, int(step))
 
     leaf_in = jax.tree_util.tree_leaves(tree)[0]
     jax.debug.callback(
@@ -262,7 +262,8 @@ def preduce(tree: Any, axes: Sequence[str] | str, tag: str = "reduce"):
     out = jax.lax.pmean(tree, axes)
     leaf_out = jax.tree_util.tree_leaves(out)[0]
     jax.debug.callback(
-        _end, jnp.zeros((), jnp.float32) * jnp.sum(leaf_out).astype(jnp.float32))
+        _end, _telemetry.current_step(),
+        jnp.zeros((), jnp.float32) * jnp.sum(leaf_out).astype(jnp.float32))
     return out
 
 

@@ -101,6 +101,7 @@ from typing import Any, Callable, Sequence
 import jax
 from jax.sharding import PartitionSpec as P
 
+from ..obs import telemetry as _telemetry
 from .collectives import preduce
 from .hf import HFConfig, hf_step
 
@@ -160,8 +161,10 @@ def data_parallel_hf_step(
         check_vma=False,
     )
     def step(params, state, batch):
+        with _telemetry.phase("curvature_primal"):
+            hvp_batch = hvp_slice(batch)
         return hf_step(
-            dloss, params, state, batch, hvp_slice(batch), config,
+            dloss, params, state, batch, hvp_batch, config,
             model_out_fn=model_out_fn,
             out_loss_fn=None if out_loss_fn is None else dout_loss,
             grad_reduce=grad_reduce,

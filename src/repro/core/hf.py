@@ -330,49 +330,57 @@ def hf_step(
         and hvp_batch is batch
         and config.solver != "gn_cg"
     )
-    # Telemetry (repro.obs): phase end-markers + the grad-reduce collective
-    # label. Every hook is a trace-time no-op unless a sink is installed —
-    # the disabled jaxpr is identical to the un-instrumented program
+    # Telemetry (repro.obs): every region runs inside its named phase scope
+    # (always on, metadata only: the device trace reads it); phase
+    # end-markers + the grad-reduce collective label are trace-time no-ops
+    # unless a sink is installed — the disabled jaxpr carries no callback
     # (tests/test_telemetry.py). step_scope hands state.step to markers
     # emitted from the curvature engine / s-step solvers.
+    phase = _telemetry.phase
+    curv_params = params      # the curvature primal's parameters (gated below)
     _telemetry.marker("step_begin", batch, step=state.step)
     with _telemetry.step_scope(state.step):
         if shared:
-            with _telemetry.collective_label("grad_reduce"):
+            with phase("grad_build"), _telemetry.collective_label("grad_reduce"):
                 f0, g, exact = shared_primal_hvp(
                     loss_fn, params, batch, grad_reduce=grad_reduce
                 )
         else:
             # ---- Alg.2 lines 3-4: full gradient (all-reduce under pjit) ----
-            f0, g = jax.value_and_grad(loss_fn)(params, batch)
-            _telemetry.marker("grad_build", f0, g, step=state.step)
+            with phase("grad_build"):
+                f0, g = jax.value_and_grad(loss_fn)(params, batch)
+                _telemetry.marker("grad_build", f0, g, step=state.step)
             if grad_reduce is not None and not config.overlap:
-                with _telemetry.collective_label("grad_reduce"):
-                    g = grad_reduce(g)
-                # Blocking schedule: close the reduce-wait explicitly so the
-                # reconstructed curvature-primal span starts AFTER the psum
-                # (the collective must show zero overlap with the build).
-                _telemetry.marker("grad_reduce", g, step=state.step)
+                with phase("grad_reduce"):
+                    with _telemetry.collective_label("grad_reduce"):
+                        g = grad_reduce(g)
+                    # With a sink, the primal is built from the parameters
+                    # the marker hands on, so it starts after the reduce.
+                    g, curv_params = _telemetry.gated_marker(
+                        "grad_reduce", g, params, step=state.step)
             # Only build the operators the solver will apply: in the
             # linearized modes construction itself runs a primal pass
             # (eagerly, outside jit).
             if config.solver != "gn_cg":
-                exact = make_hvp_op(loss_fn, params, hvp_batch, **curv_kw)
+                with phase("curvature_primal"):
+                    exact = make_hvp_op(loss_fn, curv_params, hvp_batch,
+                                        **curv_kw)
         if needs_gn:
-            if config.sstep_s > 1:
-                # The s-step solve lifts its operator to stacked
-                # multi-tangent blocks via jax.vmap (core/blocks.py). The
-                # flash-attention first-order GN tangent (linear_call) has no
-                # batching rule, so build the GN operator under the AD-closed
-                # second-order rules — plain jnp, vmappable, same math; a
-                # no-op for models that don't use flash attention
-                # (kernels/flash_ad.py).
-                with second_order_tangents():
-                    gn = make_gnvp_op(model_out_fn, out_loss_fn, params,
+            with phase("curvature_primal"):
+                if config.sstep_s > 1:
+                    # The s-step solve lifts its operator to stacked
+                    # multi-tangent blocks via jax.vmap (core/blocks.py).
+                    # The flash-attention first-order GN tangent
+                    # (linear_call) has no batching rule, so build the GN
+                    # operator under the AD-closed second-order rules —
+                    # plain jnp, vmappable, same math; a no-op for models
+                    # that don't use flash attention (kernels/flash_ad.py).
+                    with second_order_tangents():
+                        gn = make_gnvp_op(model_out_fn, out_loss_fn,
+                                          curv_params, hvp_batch, **curv_kw)
+                else:
+                    gn = make_gnvp_op(model_out_fn, out_loss_fn, curv_params,
                                       hvp_batch, **curv_kw)
-            else:
-                gn = make_gnvp_op(model_out_fn, out_loss_fn, params,
-                                  hvp_batch, **curv_kw)
         if not shared and grad_reduce is not None and config.overlap:
             # Hidden grad-reduce (overlapped schedule): the model-sized
             # gradient all-reduce has no data dependence on the curvature
@@ -384,7 +392,8 @@ def hf_step(
             # metrics["blocking_syncs"]. (The telemetry span of this very
             # collective — begin at input-ready, end at completion — is how
             # the overlap is MEASURED: obs/trace.py grad_reduce_overlap.)
-            with _telemetry.collective_label("grad_reduce"):
+            with phase("grad_reduce"), \
+                    _telemetry.collective_label("grad_reduce"):
                 g = grad_reduce(g)
     if config.solver == "gn_cg":
         G = gn
@@ -393,257 +402,272 @@ def hf_step(
     else:  # hybrid: runtime switch between the two cached linear maps
         def G(v, _state_use_gn=state.use_gn):
             return jax.lax.cond(_state_use_gn, gn, exact, v)
+    operator = G
 
-    lam = state.lam
-    A = make_damped(G, lam)
-    b = jax.tree_util.tree_map(lambda x: -x.astype(jnp.float32), g)
-    x0 = tree_scale(config.cg_decay, state.prev_delta)
-    if config.krylov_jitter > 0.0:
-        # Sharding-preserving pseudo-noise (NOT jax.random — see
-        # tree_math.tree_pseudo_noise): seeded by the gradient values, the
-        # element position and the step counter.
-        jit_tree = tree_pseudo_noise(g, state.step)
-        scale = config.krylov_jitter * jnp.maximum(tree_norm(g), 1e-8) / jnp.maximum(
-            tree_norm(jit_tree), 1e-20
-        )
-        x0 = tree_axpy(scale, jit_tree, x0)
+    def G(v):
+        # Every application of the operator — the damped product in each
+        # Krylov iteration, the s-step block product (a vmap of it), the
+        # Hutchinson probe — is its own scope nested in krylov_solve.
+        with phase("curvature_product"):
+            return operator(v)
 
     # ---- Alg.2 line 6: Krylov solve ----------------------------------------
-    # Vector backend: "tree" keeps the solve on sharding-preserving pytrees;
-    # "flat" ravels once and runs the recurrences via the fused Pallas kernels.
-    krylov_be = get_backend(config.krylov_backend, template=b)
-    m_inv = None
-    if config.precondition:
-        # The probe reuses the prebuilt operator G — under the linearized
-        # modes each Hutchinson sample is one cached-linear-map application,
-        # not a fresh re-linearization (EXPERIMENTS.md §Perf pair D).
-        diag = hutchinson_diag(G, b, state.step)
-        m_inv = jax.tree_util.tree_map(
-            lambda d: 1.0 / (jnp.abs(d) + lam) ** config.precond_alpha, diag
+    with phase("krylov_solve"):
+        lam = state.lam
+        A = make_damped(G, lam)
+        b = jax.tree_util.tree_map(lambda x: -x.astype(jnp.float32), g)
+        x0 = tree_scale(config.cg_decay, state.prev_delta)
+        if config.krylov_jitter > 0.0:
+            # Sharding-preserving pseudo-noise (NOT jax.random — see
+            # tree_math.tree_pseudo_noise): seeded by the gradient values,
+            # the element position and the step counter.
+            jit_tree = tree_pseudo_noise(g, state.step)
+            scale = (config.krylov_jitter * jnp.maximum(tree_norm(g), 1e-8)
+                     / jnp.maximum(tree_norm(jit_tree), 1e-20))
+            x0 = tree_axpy(scale, jit_tree, x0)
+
+        # Vector backend: "tree" keeps the solve on sharding-preserving
+        # pytrees; "flat" ravels once and runs the recurrences via the fused
+        # Pallas kernels.
+        krylov_be = get_backend(config.krylov_backend, template=b)
+        m_inv = None
+        if config.precondition:
+            # The probe reuses the prebuilt operator G — under the
+            # linearized modes each Hutchinson sample is one cached-linear-map
+            # application, not a fresh re-linearization (EXPERIMENTS.md §Perf
+            # pair D).
+            diag = hutchinson_diag(G, b, state.step)
+            m_inv = jax.tree_util.tree_map(
+                lambda d: 1.0 / (jnp.abs(d) + lam) ** config.precond_alpha,
+                diag)
+        with _telemetry.step_scope(state.step):
+            if config.sstep_s > 1:
+                # s-step (communication-avoiding) solve: ONE Gram reduction per
+                # cycle of sstep_s iterations, basis power chains paired into
+                # width-2 block curvature products derived from the SAME cached
+                # linearization as A (core.blocks.block_op_from_single — jax.vmap
+                # over the operator, no second primal pass). Falls back to the
+                # standard solver on basis-conditioning breakdown.
+                kind = config.sstep_solver
+                if kind == "auto":
+                    kind = "bicgstab" if config.solver == "bicgstab" else "cg"
+                sstep_fn = sstep_bicgstab if kind == "bicgstab" else sstep_cg
+                res = sstep_fn(
+                    A, b, x0, lam=lam, s=config.sstep_s,
+                    max_iters=config.max_cg_iters, tol=config.cg_tol,
+                    backend=krylov_be, A_block=block_op_from_single(A),
+                    basis=config.sstep_basis, overlap=config.overlap,
+                )
+            elif config.solver == "bicgstab":
+                res = bicgstab(A, b, x0, lam=lam, max_iters=config.max_cg_iters,
+                               tol=config.cg_tol, M_inv=m_inv, backend=krylov_be)
+            elif m_inv is not None:
+                res = pcg(A, b, x0, lam=lam, M_inv=m_inv,
+                          max_iters=config.max_cg_iters, tol=config.cg_tol,
+                          backend=krylov_be)
+            else:
+                res = cg(A, b, x0, lam=lam, max_iters=config.max_cg_iters,
+                         tol=config.cg_tol, backend=krylov_be)
+        _telemetry.marker("krylov_solve", res.residual, res.x,
+                          step=state.step)
+        _telemetry.solve_event(
+            state.step, iters=res.iters, residual=res.residual,
+            syncs=res.syncs, residual_history=res.residual_history,
+            nc_found=res.nc_found, breakdown=res.breakdown,
         )
-    with _telemetry.step_scope(state.step):
-        if config.sstep_s > 1:
-            # s-step (communication-avoiding) solve: ONE Gram reduction per
-            # cycle of sstep_s iterations, basis power chains paired into
-            # width-2 block curvature products derived from the SAME cached
-            # linearization as A (core.blocks.block_op_from_single — jax.vmap
-            # over the operator, no second primal pass). Falls back to the
-            # standard solver on basis-conditioning breakdown.
-            kind = config.sstep_solver
-            if kind == "auto":
-                kind = "bicgstab" if config.solver == "bicgstab" else "cg"
-            sstep_fn = sstep_bicgstab if kind == "bicgstab" else sstep_cg
-            res = sstep_fn(
-                A, b, x0, lam=lam, s=config.sstep_s,
-                max_iters=config.max_cg_iters, tol=config.cg_tol,
-                backend=krylov_be, A_block=block_op_from_single(A),
-                basis=config.sstep_basis, overlap=config.overlap,
-            )
-        elif config.solver == "bicgstab":
-            res = bicgstab(A, b, x0, lam=lam, max_iters=config.max_cg_iters,
-                           tol=config.cg_tol, M_inv=m_inv, backend=krylov_be)
-        elif m_inv is not None:
-            res = pcg(A, b, x0, lam=lam, M_inv=m_inv,
-                      max_iters=config.max_cg_iters, tol=config.cg_tol,
-                      backend=krylov_be)
-        else:
-            res = cg(A, b, x0, lam=lam, max_iters=config.max_cg_iters,
-                     tol=config.cg_tol, backend=krylov_be)
-    _telemetry.marker("krylov_solve", res.residual, res.x, step=state.step)
-    _telemetry.solve_event(
-        state.step, iters=res.iters, residual=res.residual, syncs=res.syncs,
-        residual_history=res.residual_history, nc_found=res.nc_found,
-        breakdown=res.breakdown,
-    )
 
     # ---- Alg.2 line 7: best descent direction among {solution, NC dir} -----
-    # Quadratic-model values come FREE from solver byproducts — no extra
-    # operator applications (each would cost a full HVP = 2 passes over the
-    # network; see EXPERIMENTS.md §Perf pair C):
-    #   A·x = b − r  (residual identity)  ⇒ m(s·x) = s·gᵀx + ½ xᵀ(b−r)
-    #   nc_dir has unit norm and measured raw curvature c = dᵀGd
-    #                                      ⇒ m(nc) = gᵀnc + ½ (c+λ)·‖nc‖²
-    # free CG-backtracking: the direction candidate is the best-model iterate
-    gx = tree_dot(g, res.x_best)
-    sign = jnp.where(jnp.sign(gx) == 0, 1.0, -jnp.sign(gx))
-    sol = tree_scale(sign, res.x_best)
-    sol_norm = tree_norm(sol)
-    xAx = tree_dot(res.x_best, jax.tree_util.tree_map(jnp.subtract, b, res.r_best))
-    m_sol = sign * gx + 0.5 * xAx
-    # λ_min(G) estimate for this solve: the solver's threaded nc_lambda
-    # (Ritz-refined on the s-step paths) floored by the probe's Rayleigh
-    # quotient, gated on the probe actually firing.
-    nc_lam = jnp.where(
-        res.nc_found, jnp.minimum(res.nc_lambda, res.nc_curv), 0.0)
-    if config.nc_mode == "escape":
-        # Saddle-free escape (Arjovsky, arXiv:1506.00059): step along the
-        # (unit-norm) NC direction at the |λ_min| scale — the magnitude the
-        # saddle-free Newton rescaling |H|⁻¹g prescribes along an
-        # eigendirection — instead of borrowing the solution's norm. The
-        # candidate is judged by the RAW (undamped) model, honest about
-        # being unbounded below along true negative curvature, so a fired
-        # probe nearly always escapes; Armijo globalizes the scale.
-        nc_scale = jnp.abs(nc_lam)
-        nc_raw = tree_scale(nc_scale, res.nc_dir)
-        nc, _ = sign_correct(g, nc_raw)
-        g_nc = tree_dot(g, nc)
-        m_nc = jnp.where(
-            res.nc_found,
-            g_nc + 0.5 * res.nc_curv * nc_scale**2,
-            jnp.inf,
-        )
-        # NaN-safe toward TAKING the step: a poisoned λ estimate (inf/NaN
-        # scale) must reach the divergence sentinel below as a non-finite
-        # step and be rejected there — `m_nc < m_sol` would silently mask
-        # it (NaN compares False) and accept the solver iterate instead.
-        take_nc = jnp.logical_and(
-            res.nc_found, jnp.logical_not(m_sol <= m_nc))
-    else:
-        # Scale the (unit-norm) NC direction to the solution's magnitude so
-        # the quadratic-model comparison and the line search see comparable
-        # steps; the quadratic model itself is unbounded below along NC
-        # directions so it prescribes no scale — floor at nc_min_step and
-        # let Armijo globalize.
-        nc_scale = jnp.maximum(sol_norm, config.nc_min_step)
-        nc_raw = tree_scale(nc_scale, res.nc_dir)
-        nc, _ = sign_correct(g, nc_raw)
-        g_nc = tree_dot(g, nc)
-        m_nc = jnp.where(
-            res.nc_found,
-            g_nc + 0.5 * (res.nc_curv + lam) * nc_scale**2,
-            jnp.inf,
-        )
-        take_nc = m_nc < m_sol
-    delta = tree_where(take_nc, nc, sol)
-    m_lin = jnp.where(take_nc, g_nc, sign * gx)       # gᵀδ
-    m_quad = jnp.where(take_nc, m_nc - g_nc, 0.5 * xAx)  # ½ δᵀAδ
+    with phase("direction"):
+        # Quadratic-model values come FREE from solver byproducts — no extra
+        # operator applications (each would cost a full HVP = 2 passes over the
+        # network; see EXPERIMENTS.md §Perf pair C):
+        #   A·x = b − r  (residual identity)  ⇒ m(s·x) = s·gᵀx + ½ xᵀ(b−r)
+        #   nc_dir has unit norm and measured raw curvature c = dᵀGd
+        #                                      ⇒ m(nc) = gᵀnc + ½ (c+λ)·‖nc‖²
+        # free CG-backtracking: the direction candidate is the best-model iterate
+        gx = tree_dot(g, res.x_best)
+        sign = jnp.where(jnp.sign(gx) == 0, 1.0, -jnp.sign(gx))
+        sol = tree_scale(sign, res.x_best)
+        sol_norm = tree_norm(sol)
+        xAx = tree_dot(res.x_best, jax.tree_util.tree_map(jnp.subtract, b, res.r_best))
+        m_sol = sign * gx + 0.5 * xAx
+        # λ_min(G) estimate for this solve: the solver's threaded nc_lambda
+        # (Ritz-refined on the s-step paths) floored by the probe's Rayleigh
+        # quotient, gated on the probe actually firing.
+        nc_lam = jnp.where(
+            res.nc_found, jnp.minimum(res.nc_lambda, res.nc_curv), 0.0)
+        if config.nc_mode == "escape":
+            # Saddle-free escape (Arjovsky, arXiv:1506.00059): step along the
+            # (unit-norm) NC direction at the |λ_min| scale — the magnitude the
+            # saddle-free Newton rescaling |H|⁻¹g prescribes along an
+            # eigendirection — instead of borrowing the solution's norm. The
+            # candidate is judged by the RAW (undamped) model, honest about
+            # being unbounded below along true negative curvature, so a fired
+            # probe nearly always escapes; Armijo globalizes the scale.
+            nc_scale = jnp.abs(nc_lam)
+            nc_raw = tree_scale(nc_scale, res.nc_dir)
+            nc, _ = sign_correct(g, nc_raw)
+            g_nc = tree_dot(g, nc)
+            m_nc = jnp.where(
+                res.nc_found,
+                g_nc + 0.5 * res.nc_curv * nc_scale**2,
+                jnp.inf,
+            )
+            # NaN-safe toward TAKING the step: a poisoned λ estimate (inf/NaN
+            # scale) must reach the divergence sentinel below as a non-finite
+            # step and be rejected there — `m_nc < m_sol` would silently mask
+            # it (NaN compares False) and accept the solver iterate instead.
+            take_nc = jnp.logical_and(
+                res.nc_found, jnp.logical_not(m_sol <= m_nc))
+        else:
+            # Scale the (unit-norm) NC direction to the solution's magnitude so
+            # the quadratic-model comparison and the line search see comparable
+            # steps; the quadratic model itself is unbounded below along NC
+            # directions so it prescribes no scale — floor at nc_min_step and
+            # let Armijo globalize.
+            nc_scale = jnp.maximum(sol_norm, config.nc_min_step)
+            nc_raw = tree_scale(nc_scale, res.nc_dir)
+            nc, _ = sign_correct(g, nc_raw)
+            g_nc = tree_dot(g, nc)
+            m_nc = jnp.where(
+                res.nc_found,
+                g_nc + 0.5 * (res.nc_curv + lam) * nc_scale**2,
+                jnp.inf,
+            )
+            take_nc = m_nc < m_sol
+        delta = tree_where(take_nc, nc, sol)
+        m_lin = jnp.where(take_nc, g_nc, sign * gx)       # gᵀδ
+        m_quad = jnp.where(take_nc, m_nc - g_nc, 0.5 * xAx)  # ½ δᵀAδ
 
-    # Degenerate solve (zero direction) → steepest descent fallback (paper:
-    # "if negative curvature at the very first CG iteration, use −g").
-    d_norm = tree_norm(delta)
-    degenerate = d_norm < 1e-12
-    delta = tree_where(degenerate, b, delta)
-    gg = tree_dot(g, g)
-    m_lin = jnp.where(degenerate, -gg, m_lin)
-    m_quad = jnp.where(degenerate, 0.0, m_quad)
+        # Degenerate solve (zero direction) → steepest descent fallback (paper:
+        # "if negative curvature at the very first CG iteration, use −g").
+        d_norm = tree_norm(delta)
+        degenerate = d_norm < 1e-12
+        delta = tree_where(degenerate, b, delta)
+        gg = tree_dot(g, g)
+        m_lin = jnp.where(degenerate, -gg, m_lin)
+        m_quad = jnp.where(degenerate, 0.0, m_quad)
 
     # ---- Alg.2 line 9: Armijo line search -----------------------------------
-    g_dot_delta = tree_dot(g, delta)
-    ls = armijo(
-        lambda p: loss_fn(p, batch), params, f0, delta, g_dot_delta,
-        c=config.ls_c, beta=config.ls_beta, max_backtracks=config.max_backtracks,
-        paired=config.overlap,
-    )
-    _telemetry.marker("line_search", ls.alpha, ls.f_new, step=state.step)
+    with phase("line_search"):
+        g_dot_delta = tree_dot(g, delta)
+        ls = armijo(
+            lambda p: loss_fn(p, batch), params, f0, delta, g_dot_delta,
+            c=config.ls_c, beta=config.ls_beta, max_backtracks=config.max_backtracks,
+            paired=config.overlap,
+        )
+        _telemetry.marker("line_search", ls.alpha, ls.f_new, step=state.step)
 
     # ---- Alg.2 lines 8,10: LM damping + parameter update --------------------
-    # predicted reduction of the STEP TAKEN: m(αδ) = α·gᵀδ + α²·½δᵀAδ
-    pred_red = ls.alpha * m_lin + ls.alpha**2 * m_quad
-    pred_red = jnp.minimum(pred_red, -1e-20)
-    lam_new, rho = damping_mod.lm_update(
-        lam, f0, ls.f_new, pred_red,
-        inc=config.damping_inc, dec=config.damping_dec,
-    )
-    new_params = tree_axpy_cast(ls.alpha, delta, params)
-    delta_taken = tree_scale(ls.alpha, delta)
+    with phase("update_damping"):
+        # predicted reduction of the STEP TAKEN: m(αδ) = α·gᵀδ + α²·½δᵀAδ
+        pred_red = ls.alpha * m_lin + ls.alpha**2 * m_quad
+        pred_red = jnp.minimum(pred_red, -1e-20)
+        lam_new, rho = damping_mod.lm_update(
+            lam, f0, ls.f_new, pred_red,
+            inc=config.damping_inc, dec=config.damping_dec,
+        )
+        new_params = tree_axpy_cast(ls.alpha, delta, params)
+        delta_taken = tree_scale(ls.alpha, delta)
 
-    # ---- divergence sentinel: reject poisoned / ascent steps ---------------
-    # A non-finite accepted loss or step (poisoned curvature batch, solver
-    # blow-up) must not reach the parameters: even the alpha=0 "zero step"
-    # is `0 * NaN = NaN` leaf-wise when delta itself is non-finite. Reject:
-    # keep params, drop the warm start (it would re-inject the poisoned
-    # direction next step), boost λ through the LM machinery, and report it
-    # (metrics["step_rejected"] + a `repro.obs` fault event). strict_descent
-    # additionally rejects real loss increases beyond the guard.
-    rejected = jnp.zeros((), bool)
-    if config.reject_nonfinite or config.strict_descent:
-        accept = jnp.ones((), bool)
-        if config.reject_nonfinite:
-            finite_ok = jnp.logical_and(
-                jnp.isfinite(ls.f_new), jnp.isfinite(tree_norm(delta_taken)))
-            accept = jnp.logical_and(accept, finite_ok)
-        if config.strict_descent:
-            guard = config.descent_guard * jnp.maximum(1.0, jnp.abs(f0))
-            accept = jnp.logical_and(accept, ls.f_new <= f0 + guard)
-        rejected = jnp.logical_not(accept)
-        boost = (config.reject_boost if config.reject_boost > 0
-                 else config.damping_inc ** 2)
-        lam_new = jnp.where(accept, lam_new,
-                            jnp.clip(lam * boost, 1e-8, 1e8))
-        rho = jnp.where(accept, rho, 0.0)
-        new_params = tree_where(accept, new_params, params)
-        delta_taken = tree_where(
-            accept, delta_taken, tree_zeros_like(state.prev_delta))
-    _telemetry.reject_event(state.step, rejected, lam_new, ls.f_new)
+        # ---- divergence sentinel: reject poisoned / ascent steps ---------------
+        # A non-finite accepted loss or step (poisoned curvature batch, solver
+        # blow-up) must not reach the parameters: even the alpha=0 "zero step"
+        # is `0 * NaN = NaN` leaf-wise when delta itself is non-finite. Reject:
+        # keep params, drop the warm start (it would re-inject the poisoned
+        # direction next step), boost λ through the LM machinery, and report it
+        # (metrics["step_rejected"] + a `repro.obs` fault event). strict_descent
+        # additionally rejects real loss increases beyond the guard.
+        rejected = jnp.zeros((), bool)
+        if config.reject_nonfinite or config.strict_descent:
+            accept = jnp.ones((), bool)
+            if config.reject_nonfinite:
+                finite_ok = jnp.logical_and(
+                    jnp.isfinite(ls.f_new), jnp.isfinite(tree_norm(delta_taken)))
+                accept = jnp.logical_and(accept, finite_ok)
+            if config.strict_descent:
+                guard = config.descent_guard * jnp.maximum(1.0, jnp.abs(f0))
+                accept = jnp.logical_and(accept, ls.f_new <= f0 + guard)
+            rejected = jnp.logical_not(accept)
+            boost = (config.reject_boost if config.reject_boost > 0
+                     else config.damping_inc ** 2)
+            lam_new = jnp.where(accept, lam_new,
+                                jnp.clip(lam * boost, 1e-8, 1e8))
+            rho = jnp.where(accept, rho, 0.0)
+            new_params = tree_where(accept, new_params, params)
+            delta_taken = tree_where(
+                accept, delta_taken, tree_zeros_like(state.prev_delta))
+        _telemetry.reject_event(state.step, rejected, lam_new, ls.f_new)
 
-    if config.solver == "hybrid_cg":
-        # NC encountered this (exact-Hessian) iteration → GN next iteration;
-        # after a GN iteration always return to the exact Hessian.
-        use_gn_next = jnp.logical_and(jnp.logical_not(state.use_gn), res.nc_found)
-    else:
-        use_gn_next = jnp.zeros((), bool)
+        if config.solver == "hybrid_cg":
+            # NC encountered this (exact-Hessian) iteration → GN next iteration;
+            # after a GN iteration always return to the exact Hessian.
+            use_gn_next = jnp.logical_and(jnp.logical_not(state.use_gn), res.nc_found)
+        else:
+            use_gn_next = jnp.zeros((), bool)
 
-    new_state = HFState(
-        lam=lam_new, prev_delta=delta_taken, use_gn=use_gn_next, step=state.step + 1
-    )
-    _telemetry.marker("update_damping", lam_new, rho, new_params, step=state.step)
-    metrics = {
-        "loss": f0,
-        "loss_new": ls.f_new,
-        "grad_norm": tree_norm(g),
-        "lambda": lam_new,
-        "rho": rho,
-        "alpha": ls.alpha,
-        "ls_evals": ls.n_evals,
-        "cg_iters": res.iters,
-        "cg_residual": res.residual,
-        # Blocking scalar-producing reductions the Krylov solve issued: one
-        # per iteration for the standard recurrences, one Gram reduction per
-        # s-iteration cycle for the s-step solvers (+ fallback iterations
-        # when the basis guard fired — sstep_fallback). The quantity the
-        # comm model's `1 + ceil(K/s) + E` counts (benchmarks/comm_model.py,
-        # measured by benchmarks/sstep_bench.py).
-        "krylov_syncs": res.syncs,
-        # Executed BLOCKING synchronizations this outer step — round-trips
-        # where the schedule stalls on a collective's result before the next
-        # one can issue: the gradient reduce (hidden behind the curvature
-        # primal build under the overlapped schedule ⇒ 0), one per Krylov
-        # sync (iterations / Gram cycles — double-buffered cycles already
-        # halve res.syncs), and one per line-search trip (candidate PAIRS
-        # under overlap ⇒ ⌈E/2⌉). The executed counterpart of
-        # comm_model.hf_sstep_syncs_per_iteration(..., overlap=).
-        "blocking_syncs": (
-            res.syncs + (ls.n_evals + 1) // 2 if config.overlap
-            else 1 + res.syncs + ls.n_evals
-        ),
-        "sstep_fallback": jnp.logical_and(config.sstep_s > 1, res.breakdown),
-        # The subset of sstep_fallback caused by the GRAM GUARD (the basis
-        # degenerating) — Bi-CG-STAB ρ/ω recurrence collapse, which the
-        # standard solver exhibits identically, is excluded. The §Perf
-        # pair G acceptance counts THIS rate.
-        "sstep_basis_fallback": jnp.logical_and(
-            config.sstep_s > 1, res.basis_breakdown),
-        # An adaptive (Newton/Chebyshev) s-step basis failed its Gram guard
-        # and the solve degraded to the monomial basis mid-stream — the
-        # first link of the basis fallback chain (always False for the
-        # standard solvers and the monomial basis).
-        "sstep_basis_degraded": jnp.logical_and(
-            config.sstep_s > 1, res.basis_degraded),
-        "nc_found": res.nc_found,
-        "nc_used": take_nc,
-        "nc_curv": res.nc_curv,
-        # λ_min(G) estimate behind the escape scale (0 when the probe did
-        # not fire): Rayleigh quotient from the standard recurrences,
-        # Ritz-refined per cycle on the s-step paths.
-        "nc_lambda": nc_lam,
-        "step_norm": tree_norm(delta_taken),
-        "used_gn": state.use_gn,
-        # Divergence sentinel (reject_nonfinite / strict_descent): the step
-        # was rejected — params unchanged, warm start dropped, λ boosted
-        # (also emitted as a `repro.obs` fault event, visible in the
-        # Perfetto trace's events lane).
-        "step_rejected": rejected,
-    }
+        new_state = HFState(
+            lam=lam_new, prev_delta=delta_taken, use_gn=use_gn_next, step=state.step + 1
+        )
+        _telemetry.marker("update_damping", lam_new, rho, new_params, step=state.step)
+        metrics = {
+            "loss": f0,
+            "loss_new": ls.f_new,
+            "grad_norm": tree_norm(g),
+            "lambda": lam_new,
+            "rho": rho,
+            "alpha": ls.alpha,
+            "ls_evals": ls.n_evals,
+            "cg_iters": res.iters,
+            "cg_residual": res.residual,
+            # Blocking scalar-producing reductions the Krylov solve ran: one
+            # per iteration for the standard recurrences, one Gram reduction per
+            # s-iteration cycle for the s-step solvers (+ fallback iterations
+            # when the basis guard fired — sstep_fallback). The quantity the
+            # comm model's `1 + ceil(K/s) + E` counts (benchmarks/comm_model.py,
+            # measured by benchmarks/sstep_bench.py).
+            "krylov_syncs": res.syncs,
+            # Executed BLOCKING synchronizations this outer step — round-trips
+            # where the schedule stalls on a collective's result before the next
+            # one can start: the gradient reduce (hidden behind the curvature
+            # primal build under the overlapped schedule ⇒ 0), one per Krylov
+            # sync (iterations / Gram cycles — double-buffered cycles already
+            # halve res.syncs), and one per line-search trip (candidate PAIRS
+            # under overlap ⇒ ⌈E/2⌉). The executed counterpart of
+            # comm_model.hf_sstep_syncs_per_iteration(..., overlap=).
+            "blocking_syncs": (
+                res.syncs + (ls.n_evals + 1) // 2 if config.overlap
+                else 1 + res.syncs + ls.n_evals
+            ),
+            "sstep_fallback": jnp.logical_and(config.sstep_s > 1, res.breakdown),
+            # The subset of sstep_fallback caused by the GRAM GUARD (the basis
+            # degenerating) — Bi-CG-STAB ρ/ω recurrence collapse, which the
+            # standard solver exhibits identically, is excluded. The §Perf
+            # pair G acceptance counts THIS rate.
+            "sstep_basis_fallback": jnp.logical_and(
+                config.sstep_s > 1, res.basis_breakdown),
+            # An adaptive (Newton/Chebyshev) s-step basis failed its Gram guard
+            # and the solve degraded to the monomial basis mid-stream — the
+            # first link of the basis fallback chain (always False for the
+            # standard solvers and the monomial basis).
+            "sstep_basis_degraded": jnp.logical_and(
+                config.sstep_s > 1, res.basis_degraded),
+            "nc_found": res.nc_found,
+            "nc_used": take_nc,
+            "nc_curv": res.nc_curv,
+            # λ_min(G) estimate behind the escape scale (0 when the probe did
+            # not fire): Rayleigh quotient from the standard recurrences,
+            # Ritz-refined per cycle on the s-step paths.
+            "nc_lambda": nc_lam,
+            "step_norm": tree_norm(delta_taken),
+            "used_gn": state.use_gn,
+            # Divergence sentinel (reject_nonfinite / strict_descent): the step
+            # was rejected — params unchanged, warm start dropped, λ boosted
+            # (also emitted as a `repro.obs` fault event, visible in the
+            # Perfetto trace's events lane).
+            "step_rejected": rejected,
+        }
+
     # Trace-time contract: the metrics dict and the published schema move in
     # lockstep (tests/test_telemetry.py::test_metrics_contract).
     assert set(metrics) == set(METRICS_SCHEMA), sorted(

@@ -160,7 +160,6 @@ from .krylov import (
 )
 from .solvers import KrylovResult, bicgstab, cg, _resolve
 from .tree_math import tree_where
-from ..obs import telemetry as _telemetry
 
 Op = Callable[[Any], Any]
 
@@ -712,7 +711,6 @@ def sstep_cg(A: Op, b, x0, *, lam, s: int, max_iters: int, tol: float = 5e-3,
             brkb = jnp.logical_or(brkb, jnp.logical_and(brk_, live))
         ritz, rok = ritz_from_segment(
             Gb[:s_boot + 1, :s_boot + 1], _segment_shift(s_boot + 1))
-        _telemetry.ritz_event(ritz, rok, basis=spec.kind)
         al, bt, gm = spec.coeffs(ritz, rok, s_run)
         nc_lam0 = _lam_floor(jnp.zeros((), jnp.float32), ritz, rok, lam)
 
@@ -735,7 +733,6 @@ def sstep_cg(A: Op, b, x0, *, lam, s: int, max_iters: int, tol: float = 5e-3,
             # p-chain, from the Gram just reduced.
             ritz, rok = ritz_from_segment(
                 G[:s_run + 1, :s_run + 1], _segment_T((al, bt, gm), s_run + 1))
-            _telemetry.ritz_event(ritz, rok, basis=spec.kind)
             nc_lam = _lam_floor(nc_lam, ritz, rok, lam)
             fa, fb_, fg = spec.coeffs(ritz, rok, s_run)
             refresh = jnp.logical_and(
@@ -1065,7 +1062,6 @@ def sstep_bicgstab(A: Op, b, x0, *, lam, s: int, max_iters: int,
                 jnp.logical_and(jnp.logical_or(brk_basis_, brk_rec_), live))
         Lb = 2 * s_boot + 1
         ritz, rok = ritz_from_segment(Gb[:Lb, :Lb], _segment_shift(Lb))
-        _telemetry.ritz_event(ritz, rok, basis=spec.kind)
         al, bt, gm = spec.coeffs(ritz, rok, 2 * s_run)
         nc_lam0 = _lam_floor(jnp.zeros((), jnp.float32), ritz, rok, lam)
 
@@ -1090,7 +1086,6 @@ def sstep_bicgstab(A: Op, b, x0, *, lam, s: int, max_iters: int,
             L = 2 * s_run + 1
             ritz, rok = ritz_from_segment(
                 G[:L, :L], _segment_T((al, bt, gm), L))
-            _telemetry.ritz_event(ritz, rok, basis=spec.kind)
             nc_lam = _lam_floor(nc_lam, ritz, rok, lam)
             fa, fb_, fg = spec.coeffs(ritz, rok, 2 * s_run)
             refresh = jnp.logical_and(

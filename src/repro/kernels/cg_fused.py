@@ -50,6 +50,7 @@ def x_update(x, p, s, alpha, gamma, *, block=BLOCK, interpret=False):
     scal = lambda v: jnp.asarray([v], jnp.float32) if jnp.ndim(v) == 0 else v.reshape(1)
     return pl.pallas_call(
         _x_update_kernel,
+        name="cg_x_update",
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((1,), lambda i: (0,)),
@@ -80,6 +81,7 @@ def residual_dots(s, As, r0s, gamma, *, block=BLOCK, interpret=False):
     scal = lambda v: jnp.asarray([v], jnp.float32) if jnp.ndim(v) == 0 else v.reshape(1)
     r, d1, d2 = pl.pallas_call(
         _residual_dots_kernel,
+        name="cg_residual_dots",
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((1,), lambda i: (0,)),
@@ -126,6 +128,7 @@ def dots_block(U, V, *, block=BLOCK_GRAM, interpret=False):
     nb = pl.cdiv(n, block)
     return pl.pallas_call(
         _dots_block_kernel,
+        name="cg_dots_block",
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((su, block), lambda i: (0, i)),
@@ -151,6 +154,7 @@ def dot2(u, v, *, block=BLOCK, interpret=False):
     nb = pl.cdiv(n, block)
     return pl.pallas_call(
         _dot2_kernel,
+        name="cg_dot2",
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((block,), lambda i: (i,)),

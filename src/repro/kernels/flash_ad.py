@@ -122,6 +122,7 @@ def flash_jvp_pass(q, k, v, o, lse, qt, kt, vt, **kkw):
 
 
 # ----------------------------------------------- second-order (jnp) entry --
+@jax.named_scope("chunked_attention")
 def _chunked_attention(q, k, v, bias=None, *, causal, window, scale,
                        valid_len, blk):
     """Attention as a checkpointed scan over query blocks — the AD-closed

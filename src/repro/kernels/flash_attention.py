@@ -365,6 +365,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, valid_len=None,
         (q, k, v), bias, blk_q, blk_k)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(B, H, nq, nk),
         in_specs=in_specs,
         out_specs=(_q_spec(blk_q, hd), _row_spec(blk_q)),
@@ -409,6 +410,7 @@ def flash_attention_dq(q, k, v, do, lse, delta, *, causal=True, window=None,
         (q, k, v, do, lse[:, :, None], delta[:, :, None]), bias, blk_q, blk_k)
     return pl.pallas_call(
         kernel,
+        name="flash_attention_dq",
         grid=(B, H, nq, nk),
         in_specs=in_specs,
         out_specs=_q_spec(blk_q, hd),
@@ -438,6 +440,7 @@ def flash_attention_dkv(q, k, v, do, lse, delta, *, causal=True, window=None,
         transposed_grid=True)
     return pl.pallas_call(
         kernel,
+        name="flash_attention_dkv",
         grid=(B, H, nk, nq),
         in_specs=in_specs,
         out_specs=(_kv_spec(blk_k, hd, 1, True), _kv_spec(blk_k, hd, 1, True)),
@@ -475,6 +478,7 @@ def flash_attention_jvp(q, k, v, qt, kt, vt, lse, *, causal=True, window=None,
         (q, k, v, qt, kt, vt, lse[:, :, None]), bias, blk_q, blk_k)
     g, t = pl.pallas_call(
         kernel,
+        name="flash_attention_jvp",
         grid=(B, H, nq, nk),
         in_specs=in_specs,
         out_specs=(_q_spec(blk_q, hd), _row_spec(blk_q)),

@@ -246,6 +246,7 @@ def flash_decode(q, k, v, bias, *, scale=None, blk_k=128, n_splits=8,
                                n_kv=KV)
     o, m, l = pl.pallas_call(
         kernel,
+        name="flash_decode",
         grid=(B, ns, n_inner),
         in_specs=[
             pl.BlockSpec((None, KV, G, hd), lambda b, s, i: (b, 0, 0, 0)),
@@ -328,6 +329,7 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, bias, *, scale=None,
     )
     o, m, l = pl.pallas_call(
         kernel,
+        name="flash_decode_paged",
         grid_spec=grid_spec,
         out_shape=_split_out_shapes(B, maxp, KV, G, hd, q.dtype),
         interpret=interpret,

@@ -63,6 +63,7 @@ def ssd_intra(u, log_a, Bv, Cv, *, interpret=False):
     kernel = functools.partial(_ssd_intra_kernel, Q=Q)
     y, S, g, l = pl.pallas_call(
         kernel,
+        name="ssd_intra",
         grid=(Bb, nc, H),
         in_specs=[
             pl.BlockSpec((1, 1, 1, Q, P), lambda b, c, h: (b, c, h, 0, 0)),

@@ -167,15 +167,15 @@ def train(
                        else contextlib.nullcontext())
             watchdog = (collectives_mod.collective_watchdog(watchdog_s)
                         if watchdog_s > 0 else contextlib.nullcontext())
+            compile_span = (sink.span("compile", step=i) if sink is not None
+                            else contextlib.nullcontext())
             tc = time.time()
-            with install, watchdog:
-                lowered = step_fn.lower(params, state, batch)
-            compiled = lowered.compile()
+            with compile_span:
+                with install, watchdog:
+                    lowered = step_fn.lower(params, state, batch)
+                compiled = lowered.compile()
             compile_s = round(time.time() - tc, 3)
             multiproc.heartbeat(i)  # compile can dwarf hang_timeout_s steps
-            if sink is not None:
-                sink.emit({"ev": "span", "name": "compile", "t0": tc,
-                           "t1": time.time(), "step": i})
         host_span = (sink.span("host_step", step=i) if sink is not None
                      else contextlib.nullcontext())
         with host_span:

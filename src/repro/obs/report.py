@@ -122,13 +122,6 @@ def render(events_dir: str, out=None) -> dict:
                           "r_last", "reduction", "nc", "breakdown"))
           if solves else "(no solve events)", file=out)
 
-    ritz = [e for e in events if e.get("ev") == "ritz"]
-    if ritz:
-        lo = min(min(e["values"]) for e in ritz if e["values"])
-        hi = max(max(e["values"]) for e in ritz if e["values"])
-        print(f"\nritz snapshots: {len(ritz)} cycle(s), "
-              f"eigenvalue range [{lo:.3e}, {hi:.3e}]", file=out)
-
     srv = serve_summary(events)
     if srv:
         print("\n== serve ==", file=out)

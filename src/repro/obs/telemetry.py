@@ -1,31 +1,45 @@
 """Per-process structured telemetry sink + trace-time instrumentation hooks.
 
-Two halves:
+Three parts:
+
+  * **Phase vocabulary** — :data:`PHASES` names the regions of an outer HF
+    step, and :func:`phase` enters ``jax.named_scope(name)`` for one of
+    them, always. A named scope is trace-time metadata: every operation
+    traced inside it carries the name in its XLA ``op_name``
+    (``jit(step)/krylov_solve/while/body/curvature_product/...``), which
+    the profiler's device trace reports per executed operation, while the
+    compiled instructions stay the same. This is how device time is split
+    by phase on the chip.
 
   * **Host side** — :class:`Telemetry` appends JSON events to
     ``events-p{N}.jsonl`` (one object per line) and offers a wall-clock
     ``span`` context manager plus instant/counter emitters for host code
-    (train loop, serve scheduler).
+    (train loop, serve scheduler). Each span also opens a
+    ``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+    shows it on the device trace's clock.
 
   * **In-jit side** — module-level trace-time state, following the
     ``core.collectives.count_executed`` pattern: while a sink is installed
-    via :func:`install`, tracing the optimizer step bakes in
-    ``jax.debug.callback`` timestamps — phase end-markers, collective
-    begin/end pairs (see ``core.collectives.preduce``), Krylov solve
-    summaries, per-cycle Ritz snapshots. With no sink installed **nothing
-    is traced in**: every hook checks ``_active`` at trace time and
-    returns before touching jax, so the disabled jaxpr is identical to the
-    un-instrumented program (zero-cost-off; asserted in
-    tests/test_telemetry.py).
+    via :func:`install`, tracing the optimizer step bakes in host-callback
+    timestamps — phase end-markers (named after the phases), collective
+    begin/end pairs (see ``core.collectives.preduce``)
+    and Krylov solve summaries. With no sink installed **nothing is traced
+    in**: every hook checks ``_active`` at trace time and returns before
+    touching jax, so the disabled jaxpr carries no callback (zero-cost-off;
+    asserted in tests/test_telemetry.py). The markers are host callbacks:
+    the right source on the CPU, not on the chip, where a sink changes the
+    program and the device trace is read instead.
 
-Timing semantics on XLA:CPU: custom calls run synchronously in the compute
-thread, so a callback's ``time.time()`` is the executor's actual schedule
-position. A collective's begin callback depends only on the reduce *input*
-(fires at input-ready = earliest possible issue time) and its end callback
-on the reduce *output* (fires at completion) — under ``HFConfig.overlap``
-the hidden grad-reduce span therefore visibly brackets the curvature
-primal build, while the blocking schedule closes it before the primal
-starts. That schedule difference is the PR's headline measurement.
+Timing semantics on XLA:CPU: a callback's ``time.time()`` is when the
+executor ran it, no earlier than the value it depends on became ready;
+two callbacks on one value reach the host in no fixed order. A
+collective's begin callback depends only on the reduce *input* and its
+end callback on the reduce *output*. Under ``HFConfig.overlap`` the hidden
+grad-reduce span therefore brackets the curvature primal build. The
+blocking schedule builds the primal from the parameters its
+``grad_reduce`` marker hands on (:func:`gated_marker`, core/hf.py), so the
+build starts after the reduce; ``obs/trace.py`` closes that phase at the
+later of its marker and the collective's end, which wait on one value.
 
 Every callback operand is multiplied by ``0 * sum(dep)`` so it stays
 data-dependent (can't be constant-folded or hoisted past the value it
@@ -42,10 +56,33 @@ from collections import deque
 from typing import Any, Optional
 
 __all__ = [
-    "Telemetry", "install", "active", "collective_label",
-    "current_collective_label", "step_scope", "marker", "solve_event",
-    "ritz_event", "reject_event", "register_crash_flush",
+    "PHASES", "phase", "Telemetry", "install", "active",
+    "collective_label", "current_collective_label", "step_scope",
+    "current_step", "marker", "gated_marker", "solve_event", "reject_event",
+    "register_crash_flush",
 ]
+
+# The regions of one outer HF step (core/hf.py), in step order, and the
+# scope nested in ``krylov_solve`` around each curvature-operator
+# application. The end-markers take their names from here; no marker
+# closes ``curvature_product`` or ``direction``.
+PHASES = (
+    "grad_build", "grad_reduce", "curvature_primal", "krylov_solve",
+    "curvature_product", "direction", "line_search", "update_damping",
+)
+
+
+@contextlib.contextmanager
+def phase(name: str):
+    """Scope the operations traced inside as phase ``name`` of the step.
+
+    Always on: ``jax.named_scope`` only names the operations (their
+    ``op_name``), adding none."""
+    if name not in PHASES:
+        raise ValueError(f"unknown phase {name!r}; one of {PHASES}")
+    import jax
+    with jax.named_scope(name):
+        yield
 
 
 class Telemetry:
@@ -81,9 +118,14 @@ class Telemetry:
     # -- host-side API ---------------------------------------------------
     @contextlib.contextmanager
     def span(self, name: str, **fields):
+        """A host span: a ``span`` event on the wall clock, and a profiler
+        ``TraceAnnotation`` of the same name on the trace's clock."""
+        from jax.profiler import TraceAnnotation
+
         t0 = time.time()
         try:
-            yield
+            with TraceAnnotation(name):
+                yield
         finally:
             t1 = time.time()
             self.emit({"ev": "span", "name": name, "t0": t0, "t1": t1,
@@ -110,14 +152,14 @@ class Telemetry:
         with self._lock:
             self._pending.setdefault(key, deque()).append(time.time())
 
-    def collective_end(self, tag: str, label: str) -> None:
+    def collective_end(self, tag: str, label: str, step: int = -1) -> None:
         t1 = time.time()
         key = (tag, label)
         with self._lock:
             q = self._pending.get(key)
             t0 = q.popleft() if q else t1
         self.emit({"ev": "coll", "tag": tag, "label": label,
-                   "t0": t0, "t1": t1})
+                   "step": int(step), "t0": t0, "t1": t1})
 
     def solve_event(self, step: int, **fields) -> None:
         self.emit({"ev": "solve", "step": int(step), "ts": time.time(),
@@ -190,6 +232,12 @@ def step_scope(step):
         _steps.pop()
 
 
+def current_step():
+    """The traced step of the innermost :func:`step_scope`, else -1."""
+    import jax.numpy as jnp
+    return _steps[-1] if _steps else jnp.int32(-1)
+
+
 def _dep_scalar(deps):
     """A zero f32 scalar data-dependent on every leaf of ``deps`` — the
     callback operand that pins a marker to its phase's outputs."""
@@ -213,14 +261,45 @@ def marker(name: str, *deps, step=None) -> None:
     if sink is None:
         return
     import jax
-    import jax.numpy as jnp
     if step is None:
-        step = _steps[-1] if _steps else jnp.int32(-1)
+        step = current_step()
 
     def _cb(s, _unused, _sink=sink, _name=name):
         _sink.phase_event(_name, int(s))
 
     jax.debug.callback(_cb, step, _dep_scalar(deps))
+
+
+def gated_marker(name: str, *deps, step=None):
+    """:func:`marker`, returning ``deps`` released only after the marker
+    ran: each floating leaf plus the zero an ``io_callback`` returns, so
+    work that reads the returned values starts after the marker reached
+    the host. A data dependence, since XLA:CPU drops optimization
+    barriers before it schedules; exact but for the sign of a zero.
+
+    No-op (nothing traced, ``deps`` returned as they are) when no sink is
+    installed."""
+    sink = _active
+    if sink is None:
+        return deps
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import io_callback
+    if step is None:
+        step = current_step()
+
+    def _cb(s, _unused, _sink=sink, _name=name):
+        _sink.phase_event(_name, int(s))
+        return np.zeros((), np.float32)
+
+    # The callback's operands carry no tangent, so differentiating through
+    # the returned values never differentiates the callback.
+    done = io_callback(_cb, jax.ShapeDtypeStruct((), jnp.float32),
+                       *jax.lax.stop_gradient((step, _dep_scalar(deps))))
+    return jax.tree_util.tree_map(
+        lambda v: v + done.astype(v.dtype)
+        if jnp.issubdtype(v.dtype, jnp.floating) else v, deps)
 
 
 def solve_event(step, *, iters, residual, syncs, residual_history,
@@ -302,26 +381,3 @@ def register_crash_flush(sink: Telemetry):
         except ValueError:
             # signal only works in the main thread; atexit still covers us.
             pass
-
-
-def ritz_event(ritz, ok, *, basis: str) -> None:
-    """Per-cycle Ritz-value snapshot from the adaptive s-step Gram
-    (free: the eigenvalues are already computed to refresh the basis).
-    No-op when no sink; otherwise fires once per executed cycle."""
-    sink = _active
-    if sink is None:
-        return
-    import jax
-    import numpy as np
-    step = _steps[-1] if _steps else None
-
-    def _cb(s, vals, okv, _sink=sink, _basis=basis):
-        v = np.asarray(vals, dtype=np.float64)
-        _sink.emit({"ev": "ritz", "step": int(s), "basis": _basis,
-                    "ok": bool(okv), "ts": time.time(),
-                    "values": [round(float(x), 8) for x in v.ravel()]})
-
-    import jax.numpy as jnp
-    if step is None:
-        step = jnp.int32(-1)
-    jax.debug.callback(_cb, step, ritz, ok)

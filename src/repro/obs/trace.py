@@ -69,14 +69,27 @@ def phase_spans(events):
     """Reconstruct ``[{pid, step, name, t0, t1}]`` from phase end-markers.
 
     Markers are grouped by (pid, step) and sorted by timestamp; each
-    marker closes the span opened by its predecessor. Consecutive markers
-    with the same name (e.g. the hybrid solver building two curvature
-    operators) collapse into one span ending at the last marker.
+    marker closes the span opened by its predecessor, a marker named after
+    a collective label no earlier than that collective's end. Consecutive
+    markers with the same name (e.g. the hybrid solver building two
+    curvature operators) collapse into one span ending at the last marker.
     """
+    # A phase named after a collective's label (the blocking schedule's
+    # grad_reduce) closes no earlier than that collective's end in the same
+    # step: its marker and the collective's end callback both wait on the
+    # reduced value, and reach the host in either order.
+    coll_end: dict = {}
+    for ev in events:
+        if ev.get("ev") == "coll" and ev.get("step", -1) >= 0:
+            k = (ev["pid"], ev["step"], ev["label"])
+            coll_end[k] = max(coll_end.get(k, ev["t1"]), ev["t1"])
     groups: dict = {}
     for ev in events:
         if ev.get("ev") == "phase":
-            groups.setdefault((ev["pid"], ev.get("step", -1)), []).append(ev)
+            key = (ev["pid"], ev.get("step", -1))
+            end = coll_end.get(key + (ev["name"],), ev["ts"])
+            groups.setdefault(key, []).append(
+                dict(ev, ts=max(ev["ts"], end)))
     spans = []
     for (pid, step), marks in groups.items():
         marks.sort(key=lambda e: e["ts"])

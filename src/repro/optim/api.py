@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from ..configs.base import HFOptConfig
 from ..core import HFConfig, hf_init, hf_step
+from ..obs import telemetry
 from .first_order import adam, momentum_sgd, sgd
 
 FIRST_ORDER = ("sgd", "momentum", "adam")
@@ -103,7 +104,8 @@ def make_optimizer(
         return Optimizer(opt.name, init, step)
 
     def step(params, state, batch):
-        hvp_batch = _slice_batch(batch, opt.hvp_batch_frac)
+        with telemetry.phase("curvature_primal"):
+            hvp_batch = _slice_batch(batch, opt.hvp_batch_frac)
         return hf_step(
             loss_fn, params, state, batch, hvp_batch, hf_cfg,
             model_out_fn=model_out_fn, out_loss_fn=out_loss_fn,

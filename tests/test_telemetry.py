@@ -217,6 +217,41 @@ def test_overlap_math_on_synthetic_events():
     assert by_pid[1]["overlap_s"] == pytest.approx(0.0, abs=1e-9)
 
 
+def test_phase_closes_after_its_collective():
+    """A blocking grad_reduce marker that reaches the host before its
+    collective's end callback (both wait on the reduced value) closes the
+    phase at the collective's end, of its own step only."""
+    evs = [
+        {"ev": "phase", "pid": 0, "name": "step_begin", "step": 3, "ts": 1.0},
+        {"ev": "phase", "pid": 0, "name": "grad_build", "step": 3, "ts": 2.0},
+        {"ev": "phase", "pid": 0, "name": "grad_reduce", "step": 3,
+         "ts": 2.5},
+        {"ev": "phase", "pid": 0, "name": "curvature_primal", "step": 3,
+         "ts": 4.0},
+        {"ev": "coll", "pid": 0, "tag": "grad_hvp", "label": "grad_reduce",
+         "step": 3, "t0": 2.1, "t1": 2.6},
+        {"ev": "coll", "pid": 0, "tag": "grad_hvp", "label": "grad_reduce",
+         "step": 4, "t0": 9.0, "t1": 9.5},
+    ]
+    spans = {s["name"]: s for s in trace.phase_spans(evs)}
+    assert (spans["grad_reduce"]["t0"], spans["grad_reduce"]["t1"]) == (
+        2.0, 2.6)
+    assert spans["curvature_primal"]["t0"] == 2.6
+    (row,) = trace.grad_reduce_overlap(evs)
+    assert row["overlap_s"] == 0.0
+
+
+def test_blocking_step_adds_nothing_when_disabled(setup):
+    """No sink: the blocking schedule's standalone gradient reduce brings
+    no callback and no optimization barrier into the step; the order of
+    reduce and curvature primal is XLA's, as with no telemetry at all."""
+    model, params, data, mesh = setup
+    cfg = HFConfig(solver="hessian_cg", max_cg_iters=4, cg_tol=0.0)
+    step = data_parallel_hf_step(model.loss_fn, mesh, cfg, hvp_frac=0.5)
+    jx = str(jax.make_jaxpr(step)(params, hf_init(params, cfg), data))
+    assert "callback" not in jx and "optimization_barrier" not in jx
+
+
 def test_build_trace_structure(tmp_path):
     d = str(tmp_path)
     with open(os.path.join(d, "events-p0.jsonl"), "w") as f:
