@@ -49,6 +49,14 @@ parallelism) and the inner loop is bandwidth-bound. The pytree ("tree")
 backend keeps per-tensor shardings instead and wins when params are sharded
 under pjit. ``benchmarks/kernels_bench.py`` compares both end-to-end.
 
+Curvature (the Krylov solve's operator, see kernels/mlp_hvp.py):
+
+  * ``mlp_hvp``               — the exact Hessian-vector product of a tanh
+                                MLP with a softmax cross-entropy head in one
+                                pass over row tiles, from the primal pass's
+                                residuals (models/mlp.py routes the MLP's
+                                exact curvature product here on the TPU).
+
 ``interpret=True`` runs the kernel bodies in Python on CPU (how this repo
 validates them); on a real TPU pass interpret=False (default resolves from
 the backend).
@@ -61,6 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from . import cg_fused, flash_ad, flash_attention as fa, flash_decode as fd
+from . import mlp_hvp as mh
 from .flash_ad import second_order_tangents  # re-export (curvature engine)
 from .flash_decode import decode_bias, paged_bias  # re-export (mask->bias)
 
@@ -243,3 +252,47 @@ def dot2(u, v, *, interpret=None):
     vp, _ = _pad_flat(v, cg_fused.BLOCK)
     d1, d2 = cg_fused.dot2(up, vp, interpret=interpret)
     return jnp.sum(d1), jnp.sum(d2)
+
+
+def _round_up(n, m):
+    return -(-n // m) * m
+
+
+def _pad_to(a, shape):
+    pads = [(0, t - s) for s, t in zip(a.shape, shape)]
+    return jnp.pad(a, pads) if any(hi for _, hi in pads) else a
+
+
+def mlp_hvp(acts, errs, p, y, weights, dweights, dbiases, *,
+            block_rows=mh.BLOCK_ROWS, interpret=None):
+    """Exact Hessian-vector product of a tanh MLP with a mean softmax
+    cross-entropy head over the rows of ``acts[0]``.
+
+    ``acts``: the layers' inputs ``x, a_1 … a_{L-1}`` (``[n, d]`` f32);
+    ``errs``: ``∂loss/∂a_l`` for ``l = 1 … L-1``; ``p``: the softmax
+    ``[n, classes]``; ``y``: ``[n]`` integer labels; ``weights``: the L
+    ``[d_in, d_out]`` matrices; ``dweights``/``dbiases``: the direction.
+    Returns the product's ``(d∇W list, d∇b list)`` in f32.
+
+    Widths are zero-padded to 128 lanes here (the padded columns add exact
+    zeros); under ``jax.linearize`` the residuals' padding and casts run
+    once, in the primal pass, and each product pads only the direction.
+    """
+    interpret = _default_interpret() if interpret is None else interpret
+    n = acts[0].shape[0]
+    dims = [acts[0].shape[1]] + [w.shape[1] for w in weights]
+    pad = [_round_up(d, 128) for d in dims]
+    acts = [_pad_to(a.astype(jnp.float32), (n, d))
+            for a, d in zip(acts, pad)]
+    errs = [_pad_to(e.astype(jnp.float32), (n, d))
+            for e, d in zip(errs, pad[1:])]
+    p = _pad_to(p.astype(jnp.float32), (n, pad[-1]))
+    mats = lambda ws: [_pad_to(w.astype(jnp.bfloat16), (a, b))
+                       for w, a, b in zip(ws, pad, pad[1:])]
+    dbs = [_pad_to(b.astype(jnp.float32).reshape(1, -1), (1, d))
+           for b, d in zip(dbiases, pad[1:])]
+    gw, gb = mh.mlp_hvp(acts, errs, p, y.astype(jnp.int32).reshape(n, 1),
+                        mats(weights), mats(dweights), dbs,
+                        block_rows=block_rows, interpret=interpret)
+    return ([g[:a, :b] for g, a, b in zip(gw, dims, dims[1:])],
+            [g[0, :b] for g, b in zip(gb, dims[1:])])

@@ -184,3 +184,42 @@ def dot2_ref(u, v):
     """(<u,v>, <v,v>) in f32."""
     uf, vf = u.astype(jnp.float32), v.astype(jnp.float32)
     return jnp.vdot(uf, vf), jnp.vdot(vf, vf)
+
+
+def mlp_hvp_ref(acts, errs, p, y, weights, dweights, dbiases, *,
+                mxu_dtype=jnp.bfloat16):
+    """The tanh MLP's exact Hessian-vector product (kernels/mlp_hvp.py's
+    R-operator) over all rows at once, each matmul's operands rounded to
+    ``mxu_dtype`` and accumulated in f32, as the kernel rounds them; at
+    ``mxu_dtype=float32`` (and ``highest`` precision) it is
+    ``jax.jvp(jax.grad(loss))``. Returns (d∇W list, d∇b list)."""
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+
+    def mm(u, w, dims):
+        return jax.lax.dot_general(u.astype(mxu_dtype), w.astype(mxu_dtype),
+                                   (dims, ((), ())), precision=hi,
+                                   preferred_element_type=f32)
+
+    L, n = len(weights), p.shape[0]
+    da, dz = [None], mm(acts[0], dweights[0], ((1,), (0,))) + dbiases[0]
+    for l in range(1, L):
+        da.append((1.0 - acts[l] * acts[l]) * dz)
+        dz = (mm(da[l], weights[l], ((1,), (0,)))
+              + mm(acts[l], dweights[l], ((1,), (0,))) + dbiases[l])
+    inv_b = 1.0 / n
+    delta = (p - jax.nn.one_hot(y, p.shape[1], dtype=f32)) * inv_b
+    ddelta = p * (dz - jnp.sum(p * dz, axis=1, keepdims=True)) * inv_b
+    gw, gb = [None] * L, [None] * L
+    for l in reversed(range(L)):
+        gw[l] = mm(acts[l], ddelta, ((0,), (0,)))
+        if l:
+            gw[l] = gw[l] + mm(da[l], delta, ((0,), (0,)))
+        gb[l] = jnp.sum(ddelta, axis=0)
+        if l:
+            de = (mm(ddelta, weights[l], ((1,), (1,)))
+                  + mm(delta, dweights[l], ((1,), (1,))))
+            one_m = 1.0 - acts[l] * acts[l]
+            ddelta = one_m * de - 2.0 * acts[l] * da[l] * errs[l - 1]
+            delta = one_m * errs[l - 1]
+    return gw, gb

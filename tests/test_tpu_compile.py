@@ -12,6 +12,7 @@ one process may load the TPU library at a time, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +31,10 @@ W, N_PAGES, PAGE, MAX_PAGES = 4096, 64, 128, 32
 # flat Krylov vectors: the TIMIT Fig. 5 network's parameter count, and
 # qwen1.5-0.5b's (both not a multiple of the kernels' blocks)
 N_PARAMS = (1_722_293, 463_987_712)
+# the TIMIT Fig. 5 network and the curvature rows of the benchmark's two
+# cells (batch 163840 and 16384 at a quarter)
+TIMIT = (360, 512, 512, 512, 1973)
+CURV_ROWS = (40960, 4096)
 
 
 @pytest.fixture(scope="module")
@@ -120,3 +125,44 @@ def test_flash_decode_compiles(chip, layout, hd):
         _assert_kernel(lambda q, k, v, t, b: ops.flash_decode_paged(
             q, k, v, t, b, interpret=False), q, pool, pool,
             chip((B, MAX_PAGES), jnp.int32), chip((B, MAX_PAGES * PAGE)))
+
+
+@pytest.mark.parametrize("n", CURV_ROWS)
+def test_mlp_hvp_compiles(chip, n):
+    """The fused exact curvature product at the TIMIT net's widths."""
+    acts = [chip((n, d)) for d in TIMIT[:-1]]
+    errs = [chip((n, d)) for d in TIMIT[1:-1]]
+    ws = [chip((a, b)) for a, b in zip(TIMIT, TIMIT[1:])]
+    dbs = [chip((b,)) for b in TIMIT[1:]]
+    _assert_kernel(lambda a, e, p, y, w, dw, db: ops.mlp_hvp(
+        a, e, p, y, w, dw, db, interpret=False), acts, errs,
+        chip((n, TIMIT[-1])), chip((n,), jnp.int32), ws, ws, dbs)
+
+
+def test_hf_step_runs_mlp_hvp_in_curvature_product(chip, monkeypatch):
+    """The TIMIT net's HF step as the chip compiles it: every launch of the
+    fused product in the compiled step sits under ``curvature_product``."""
+    from repro.configs import HFOptConfig
+    from repro.models import build_mlp
+    from repro.optim import make_optimizer
+
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    model = build_mlp(TIMIT)
+    opt = make_optimizer(HFOptConfig(name="bicgstab", hvp_batch_frac=0.25),
+                         model.loss_fn, model_out_fn=model.logits_fn,
+                         out_loss_fn=model.out_loss_fn)
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype), t)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(opt.init, params)
+    batch = {"x": chip((CURV_ROWS[1], TIMIT[0])),
+             "y": chip((CURV_ROWS[1],), jnp.int32)}
+    text = jax.jit(opt.step).lower(on_chip(params), on_chip(state),
+                                   batch).compile().as_text()
+    launches = [re.search(r'op_name="([^"]*)"', line).group(1)
+                for line in text.splitlines()
+                if "tpu_custom_call" in line and "mlp_hvp" in line]
+    # the initial residual's product and the loop body's two
+    assert len(launches) == 3
+    assert all("/curvature_product/" in name and "mlp_hvp" in name
+               for name in launches)
