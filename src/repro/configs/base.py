@@ -46,7 +46,9 @@ class ModelConfig:
     # --- encoder-decoder (whisper) ---
     is_encoder_decoder: bool = False
     n_encoder_layers: int = 0
-    n_audio_frames: int = 1500                # stub frontend output length
+    n_audio_frames: int = 1500                # encoder positions: the conv stem's
+                                              # output, half the log-mel frames
+    n_mels: int = 80                          # log-mel bins the conv stem reads
     max_target_positions: Optional[int] = None
     # --- vlm (phi-3-vision) ---
     n_vision_tokens: int = 0                  # stub patch embeddings
@@ -138,6 +140,8 @@ class ModelConfig:
         if self.is_encoder_decoder:
             blocks += self.n_encoder_layers * (attn + mlp + 2 * d)
             blocks += self.n_layers // len(self.block_pattern) * (attn + 2 * d)  # cross-attn
+            blocks += 3 * self.n_mels * d + 3 * d * d + 2 * d       # conv stem
+            blocks += (self.max_target_positions or 0) * d          # learned positions
         emb = V * d
         head = 0 if self.tie_embeddings else V * d
         return emb + blocks + head + d

@@ -17,7 +17,9 @@ def lm_batch(key, cfg, batch_size: int, seq_len: int):
     """Synthetic next-token batch for any assigned architecture.
 
     Tokens follow a noisy periodic process so there is learnable structure.
-    For vlm/audio families the stubbed modality embeddings are included.
+    The vlm family gets stubbed patch embeddings; the audio family gets
+    log-mel frames (``mel``: 2·n_audio_frames frames of n_mels bins, which
+    the conv stem halves to the encoder's positions).
     """
     k1, k2, k3 = jax.random.split(key, 3)
     text_len = seq_len - (cfg.n_vision_tokens if cfg.family == "vlm" else 0)
@@ -39,8 +41,8 @@ def lm_batch(key, cfg, batch_size: int, seq_len: int):
             k3, (batch_size, cfg.n_vision_tokens, cfg.vision_dim), jnp.float32
         ).astype(jnp.dtype(cfg.dtype))
     if cfg.family == "audio":
-        batch["audio_embed"] = jax.random.normal(
-            k3, (batch_size, cfg.n_audio_frames, cfg.d_model), jnp.float32
+        batch["mel"] = jax.random.normal(
+            k3, (batch_size, 2 * cfg.n_audio_frames, cfg.n_mels), jnp.float32
         ).astype(jnp.dtype(cfg.dtype))
     return batch
 
@@ -59,8 +61,8 @@ def batch_spec(cfg, batch_size: int, seq_len: int, kind: str = "train"):
             (batch_size, cfg.n_vision_tokens, cfg.vision_dim), jnp.dtype(cfg.dtype)
         )
     if cfg.family == "audio":
-        spec["audio_embed"] = sds(
-            (batch_size, cfg.n_audio_frames, cfg.d_model), jnp.dtype(cfg.dtype)
+        spec["mel"] = sds(
+            (batch_size, 2 * cfg.n_audio_frames, cfg.n_mels), jnp.dtype(cfg.dtype)
         )
     return spec
 

@@ -46,10 +46,9 @@ from .sharding import batch_specs, cache_specs, param_specs, to_shardings
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 # long_500k needs sub-quadratic attention: dense/vlm archs run a
-# sliding-window variant (window below); whisper is skipped (its decoder
-# domain is capped at 448 positions — see DESIGN.md §6).
+# sliding-window variant (window below). Whisper's decoder has 448 learned
+# positions, so every shape longer than that is skipped for it.
 LONG_CONTEXT_WINDOW = 8192
-LONG_SKIP = {"whisper-small": "enc-dec decoder capped at 448 target positions"}
 # sLSTM recurs sequentially over time: a 524288-step lax.scan is lowerable
 # but not a deployable prefill; xlstm long-context decode still exercises it
 # (single step), which is the case that matters.
@@ -175,9 +174,10 @@ def run_one(arch_id: str, shape_name: str, *, multi_pod: bool, solver="bicgstab"
         "ce_chunk": ce_chunk, "shard_cache_hd": shard_cache_hd,
         "shard_hints": shard_hints,
     }
-    if shape_name == "long_500k" and arch_id in LONG_SKIP:
+    cap = get_config(arch_id).max_target_positions
+    if cap is not None and INPUT_SHAPES[shape_name].seq_len > cap:
         rec["status"] = "skipped"
-        rec["reason"] = LONG_SKIP[arch_id]
+        rec["reason"] = f"the decoder has {cap} positions"
         return rec
     t0 = time.time()
     try:

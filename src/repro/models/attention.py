@@ -1,5 +1,10 @@
 """GQA attention: training/prefill (full-sequence) and single-token decode
 against a rolling KV cache (bounded by the sliding window when configured).
+
+The training-path entry points (``attend_full``: causal self- and
+cross-attention; ``encoder_attend``) run inside ``jax.named_scope
+("attention")``, so every device operation of the attention sublayer,
+projections included, carries ``attention`` in its ``op_name``.
 """
 from __future__ import annotations
 
@@ -65,6 +70,7 @@ def causal_mask(S, T=None, *, window: Optional[int] = None, offset: int = 0):
     return m[None, None]
 
 
+@jax.named_scope("attention")
 def attend_full(p, x, positions, cfg, *, mask=None, cross_kv=None):
     """Training/prefill attention. x:(B,S,d). Returns (B,S,d).
 
@@ -117,6 +123,7 @@ def attend_full(p, x, positions, cfg, *, mask=None, cross_kv=None):
     return dense(p["wo"], out.reshape(B, S, H * hd))
 
 
+@jax.named_scope("attention")
 def encoder_attend(p, x, cfg):
     """Bidirectional self-attention (whisper encoder): no mask, no RoPE.
     Runs the non-causal flash kernel under ``cfg.use_flash_attention``."""

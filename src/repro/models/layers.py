@@ -47,23 +47,41 @@ def apply_norm(p, x, eps=1e-5):
     return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
 
 
-def mlp_init(key, d, d_ff, dtype, act="swiglu"):
+def mlp_init(key, d, d_ff, dtype, act="swiglu", bias=False):
     k1, k2, k3 = jax.random.split(key, 3)
     if act == "swiglu":
         return {
-            "wi": dense_init(k1, d, d_ff, dtype),
-            "wg": dense_init(k2, d, d_ff, dtype),
-            "wo": dense_init(k3, d_ff, d, dtype),
+            "wi": dense_init(k1, d, d_ff, dtype, bias=bias),
+            "wg": dense_init(k2, d, d_ff, dtype, bias=bias),
+            "wo": dense_init(k3, d_ff, d, dtype, bias=bias),
             }
-    return {"wi": dense_init(k1, d, d_ff, dtype), "wo": dense_init(k3, d_ff, d, dtype)}
+    return {"wi": dense_init(k1, d, d_ff, dtype, bias=bias),
+            "wo": dense_init(k3, d_ff, d, dtype, bias=bias)}
 
 
 def apply_mlp(p, x):
     if "wg" in p:
         h = jax.nn.silu(dense(p["wg"], x)) * dense(p["wi"], x)
     else:
-        h = jax.nn.gelu(dense(p["wi"], x))
+        h = jax.nn.gelu(dense(p["wi"], x), approximate=False)
     return dense(p["wo"], h)
+
+
+def conv_init(key, width, d_in, d_out, dtype):
+    """A 1-d convolution's {"w": (width, d_in, d_out), "b": (d_out,)}:
+    N(0, 1/(width·d_in)) weights, zero bias."""
+    w = jax.random.normal(key, (width, d_in, d_out)) / jnp.sqrt(width * d_in)
+    return {"w": w.astype(dtype), "b": jnp.zeros((d_out,), dtype)}
+
+
+def conv1d(p, x, stride=1):
+    """x: (B, T, d_in) -> (B, ceil(T / stride), d_out), zero-padded by
+    width // 2 on each side (whisper's audio stem)."""
+    pad = p["w"].shape[0] // 2
+    y = jax.lax.conv_general_dilated(
+        x, p["w"], window_strides=(stride,), padding=[(pad, pad)],
+        dimension_numbers=("NWC", "WIO", "NWC"))
+    return y + p["b"]
 
 
 def embedding_init(key, vocab, d, dtype):

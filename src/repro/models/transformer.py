@@ -37,6 +37,8 @@ from .attention import (
 from .layers import (
     apply_mlp,
     apply_norm,
+    conv1d,
+    conv_init,
     dense,
     dense_init,
     dtype_of,
@@ -650,67 +652,94 @@ def _chunked_ce(x, w, targets, mask, chunk, *, vocab_major: bool):
 
 
 # ------------------------------------------------------- encoder-decoder ---
-def _enc_unit_init(key, cfg, dtype):
+def _whisper_attn_init(key, cfg, dtype):
+    """Whisper's attention: biases on the query, value and output
+    projections, none on the key."""
     from .attention import attn_init
+    p = attn_init(key, cfg, dtype)
+    for name in ("wq", "wv", "wo"):
+        p[name]["b"] = jnp.zeros((p[name]["w"].shape[1],), dtype)
+    return p
+
+
+def _enc_unit_init(key, cfg, dtype):
     k1, k2 = jax.random.split(key)
     d = cfg.d_model
     return {
         "norm1": norm_init(d, dtype, cfg.norm_kind),
-        "attn": attn_init(k1, cfg, dtype),
+        "attn": _whisper_attn_init(k1, cfg, dtype),
         "norm2": norm_init(d, dtype, cfg.norm_kind),
-        "mlp": mlp_init(k2, d, cfg.d_ff, dtype, cfg.mlp_act),
+        "mlp": mlp_init(k2, d, cfg.d_ff, dtype, cfg.mlp_act, bias=True),
     }
 
 
 def _dec_unit_init(key, cfg, dtype):
-    from .attention import attn_init
     k1, k2, k3 = jax.random.split(key, 3)
     d = cfg.d_model
     return {
         "norm1": norm_init(d, dtype, cfg.norm_kind),
-        "self_attn": attn_init(k1, cfg, dtype),
+        "self_attn": _whisper_attn_init(k1, cfg, dtype),
         "norm2": norm_init(d, dtype, cfg.norm_kind),
-        "cross_attn": attn_init(k2, cfg, dtype),
+        "cross_attn": _whisper_attn_init(k2, cfg, dtype),
         "norm3": norm_init(d, dtype, cfg.norm_kind),
-        "mlp": mlp_init(k3, d, cfg.d_ff, dtype, cfg.mlp_act),
+        "mlp": mlp_init(k3, d, cfg.d_ff, dtype, cfg.mlp_act, bias=True),
     }
 
 
-def sinusoidal_positions(n, d):
-    pos = jnp.arange(n)[:, None].astype(jnp.float32)
-    dim = jnp.arange(0, d, 2)[None, :].astype(jnp.float32)
-    ang = pos / jnp.power(10000.0, dim / d)
-    pe = jnp.zeros((n, d), jnp.float32)
-    pe = pe.at[:, 0::2].set(jnp.sin(ang)).at[:, 1::2].set(jnp.cos(ang))
-    return pe
+def whisper_sinusoids(length, channels):
+    """Whisper's fixed encoder positions: sines over the first half of the
+    channels, cosines over the second, timescales from 1 to 10000."""
+    inc = jnp.log(10000.0) / (channels // 2 - 1)
+    inv = jnp.exp(-inc * jnp.arange(channels // 2, dtype=jnp.float32))
+    t = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None]
+    return jnp.concatenate([jnp.sin(t), jnp.cos(t)], axis=1)
 
 
 def build_encdec_model(cfg, *, remat: bool = False) -> ModelApi:
-    """Whisper-style: bidirectional encoder over (stub) audio-frame embeddings,
-    causal decoder with per-layer cross attention. Sinusoidal positions on
-    both sides (whisper uses learned decoder positions capped at 448; we use
-    sinusoidal so arbitrary dry-run lengths are well-formed — see DESIGN.md)."""
+    """Whisper (arXiv:2212.04356): a conv stem over log-mel frames (conv
+    width 3 from ``n_mels`` to d_model, GELU, conv width 3 stride 2, GELU),
+    fixed sinusoidal positions, a bidirectional pre-LN encoder; a pre-LN
+    decoder with learned positions (``max_target_positions`` of them),
+    causal self-attention and per-layer cross-attention; exact GELU MLPs;
+    biases on every dense layer but the key projections; the head tied to
+    the token embedding, over the unpadded vocabulary.
+
+    Batches carry ``mel`` (B, 2·n_audio_frames, n_mels), ``tokens``,
+    ``targets`` and ``loss_mask`` (B, S) with S <= max_target_positions.
+    The head and the masked cross-entropy run in ``jax.named_scope
+    ("lm_head")``; the cross-attention's key and value projections of the
+    encoder's output run in ``jax.named_scope("attention")``, as the
+    attention sublayers do."""
     dtype = dtype_of(cfg)
-    V = cfg.padded_vocab
+    V = cfg.vocab_size
 
     def init(key):
-        kE, kEnc, kDec, kH = jax.random.split(key, 4)
+        kC1, kC2, kE, kP, kEnc, kDec = jax.random.split(key, 6)
+        d = cfg.d_model
         return {
-            "embed": embedding_init(kE, V, cfg.d_model, dtype),
+            "conv1": conv_init(kC1, 3, cfg.n_mels, d, dtype),
+            "conv2": conv_init(kC2, 3, d, d, dtype),
             "enc_blocks": jax.vmap(lambda k: _enc_unit_init(k, cfg, dtype))(
                 jax.random.split(kEnc, cfg.n_encoder_layers)
             ),
-            "enc_norm": norm_init(cfg.d_model, dtype, cfg.norm_kind),
+            "enc_norm": norm_init(d, dtype, cfg.norm_kind),
+            "embed": embedding_init(kE, V, d, dtype),
+            "dec_pos": (jax.random.normal(kP, (cfg.max_target_positions, d))
+                        * 0.02).astype(dtype),
             "dec_blocks": jax.vmap(lambda k: _dec_unit_init(k, cfg, dtype))(
                 jax.random.split(kDec, cfg.n_layers)
             ),
-            "final_norm": norm_init(cfg.d_model, dtype, cfg.norm_kind),
-            "lm_head": dense_init(kH, cfg.d_model, V, dtype),
+            "final_norm": norm_init(d, dtype, cfg.norm_kind),
         }
 
-    def encode(params, audio_embed):
-        x = audio_embed.astype(dtype)
-        x = x + sinusoidal_positions(x.shape[1], cfg.d_model).astype(dtype)[None]
+    def encode(params, mel):
+        x = jax.nn.gelu(conv1d(params["conv1"], mel.astype(dtype)),
+                        approximate=False)
+        x = jax.nn.gelu(conv1d(params["conv2"], x, stride=2), approximate=False)
+        if x.shape[1] != cfg.n_audio_frames:
+            raise ValueError(f"{mel.shape[1]} mel frames give {x.shape[1]} "
+                             f"encoder positions, not {cfg.n_audio_frames}")
+        x = x + whisper_sinusoids(x.shape[1], cfg.d_model).astype(dtype)[None]
 
         def body(xx, unit):
             h = apply_norm(unit["norm1"], xx, cfg.norm_eps)
@@ -721,6 +750,7 @@ def build_encdec_model(cfg, *, remat: bool = False) -> ModelApi:
         x, _ = jax.lax.scan(_make_remat(body, remat), x, params["enc_blocks"])
         return apply_norm(params["enc_norm"], x, cfg.norm_eps)
 
+    @jax.named_scope("attention")
     def _cross_kv(unit, enc_out):
         KV, hd = cfg.n_kv_heads, cfg.resolved_head_dim
         k = _split_heads(dense(unit["cross_attn"]["wk"], enc_out), KV, hd)
@@ -728,9 +758,13 @@ def build_encdec_model(cfg, *, remat: bool = False) -> ModelApi:
         return k, v
 
     def decode_seq(params, tokens, enc_out, *, produce_cache=False, max_len=None):
-        x = embed(params["embed"], tokens)
-        S = x.shape[1]
-        x = x + sinusoidal_positions(S, cfg.d_model).astype(dtype)[None]
+        """The decoder's final-norm hidden states (and, for prefill, its
+        caches)."""
+        S = tokens.shape[1]
+        if S > cfg.max_target_positions:
+            raise ValueError(f"{S} decoder positions; whisper has "
+                             f"{cfg.max_target_positions}")
+        x = embed(params["embed"], tokens) + params["dec_pos"][:S][None]
         positions = jnp.arange(S)
 
         def body(xx, unit):
@@ -747,16 +781,22 @@ def build_encdec_model(cfg, *, remat: bool = False) -> ModelApi:
             return xx, ((kv, ck, cv) if produce_cache else None)
 
         x, caches = jax.lax.scan(_make_remat(body, remat), x, params["dec_blocks"])
-        x = apply_norm(params["final_norm"], x, cfg.norm_eps)
-        return dense(params["lm_head"], x).astype(jnp.float32), caches
+        return apply_norm(params["final_norm"], x, cfg.norm_eps), caches
+
+    @jax.named_scope("lm_head")
+    def head(params, x):
+        return unembed(params["embed"], x)
 
     def logits_fn(params, batch):
-        enc_out = encode(params, batch["audio_embed"])
-        logits, _ = decode_seq(params, batch["tokens"], enc_out)
-        return logits
+        x, _ = decode_seq(params, batch["tokens"], encode(params, batch["mel"]))
+        return head(params, x)
+
+    @jax.named_scope("lm_head")
+    def out_loss_fn(logits, batch):
+        return _ce_loss(logits, batch)
 
     def loss_fn(params, batch):
-        return _ce_loss(logits_fn(params, batch), batch)
+        return out_loss_fn(logits_fn(params, batch), batch)
 
     def init_cache(batch_size, max_len):
         KV, hd, F = cfg.n_kv_heads, cfg.resolved_head_dim, cfg.n_audio_frames
@@ -774,16 +814,16 @@ def build_encdec_model(cfg, *, remat: bool = False) -> ModelApi:
         return {"self": kv, "cross_k": cross[0], "cross_v": cross[1]}
 
     def prefill(params, batch, max_len):
-        enc_out = encode(params, batch["audio_embed"])
-        logits, caches = decode_seq(
+        enc_out = encode(params, batch["mel"])
+        x, caches = decode_seq(
             params, batch["tokens"], enc_out, produce_cache=True, max_len=max_len
         )
         kv, ck, cv = caches
-        return logits[:, -1:], {"self": kv, "cross_k": ck, "cross_v": cv}
+        return head(params, x[:, -1:]), {"self": kv, "cross_k": ck, "cross_v": cv}
 
     def decode_step(params, token, t, caches):
-        x = embed(params["embed"], token)
-        x = x + _sin_pos_at(t, cfg.d_model).astype(dtype)
+        pos = jax.lax.dynamic_slice_in_dim(params["dec_pos"], t, 1, axis=0)
+        x = embed(params["embed"], token) + pos[None]
 
         def body(xx, xs):
             unit, kv, ck, cv = xs
@@ -799,16 +839,9 @@ def build_encdec_model(cfg, *, remat: bool = False) -> ModelApi:
             body, x, (params["dec_blocks"], caches["self"], caches["cross_k"], caches["cross_v"])
         )
         x = apply_norm(params["final_norm"], x, cfg.norm_eps)
-        logits = dense(params["lm_head"], x).astype(jnp.float32)
-        return logits, {"self": nkv, "cross_k": caches["cross_k"], "cross_v": caches["cross_v"]}
+        return head(params, x), {"self": nkv, "cross_k": caches["cross_k"],
+                                 "cross_v": caches["cross_v"]}
 
     return ModelApi(
-        cfg, init, loss_fn, logits_fn, _ce_loss, prefill, decode_step, init_cache
+        cfg, init, loss_fn, logits_fn, out_loss_fn, prefill, decode_step, init_cache
     )
-
-
-def _sin_pos_at(t, d):
-    dim = jnp.arange(0, d, 2).astype(jnp.float32)
-    ang = t.astype(jnp.float32) / jnp.power(10000.0, dim / d)
-    pe = jnp.zeros((d,), jnp.float32)
-    return pe.at[0::2].set(jnp.sin(ang)).at[1::2].set(jnp.cos(ang))[None, None]

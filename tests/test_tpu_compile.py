@@ -35,6 +35,10 @@ N_PARAMS = (1_722_293, 463_987_712)
 # cells (batch 163840 and 16384 at a quarter)
 TIMIT = (360, 512, 512, 512, 1973)
 CURV_ROWS = (40960, 4096)
+# whisper-small's attention at its published lengths: 4 utterances, 12
+# heads of 64, f32; (queries, keys, causal)
+WHISPER = {"encoder": (1500, 1500, False), "causal": (448, 448, True),
+           "cross": (448, 1500, False)}
 
 
 @pytest.fixture(scope="module")
@@ -108,6 +112,21 @@ def test_flash_attention_grad_unaligned_compiles(chip, hd):
     def loss(q, k, v):
         o = ops.flash_attention(q, k, v, interpret=False)
         return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    _assert_kernel(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+
+
+@pytest.mark.parametrize("kind", sorted(WHISPER))
+def test_flash_attention_grad_whisper_compiles(chip, kind):
+    """The gradient's flash passes at whisper's lengths, off the 128 tile:
+    the encoder's 1500 frames, the decoder's 448 tokens, and the cross
+    attention between them."""
+    sq, sk, causal = WHISPER[kind]
+    q, kv = chip((4, sq, 12, 64)), chip((4, sk, 12, 64))
+
+    def loss(q, k, v):
+        o = ops.flash_attention(q, k, v, causal=causal, interpret=False)
+        return jnp.sum(o ** 2)
 
     _assert_kernel(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
 
